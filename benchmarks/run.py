@@ -6,8 +6,9 @@ CPU-friendly.  ``--smoke`` runs a fast CI subset (table2 at n=256, the LU
 kernel-impl shootout at n∈{256, 1024}, the banded kernel shootout at the
 paper's n=16384 / bw=16, the optimizer trajectory, and the serving rows —
 decode host-sync before/after, ragged continuous batching, solve-service
-cache speedup, plus the 8-device SPIKE substitution row timed in a
-subprocess) and writes ``BENCH_kernels.json`` (name → us_per_call) at
+cache speedup, plus the SPIKE substitution row: 8 forced host devices in
+a CPU subprocess under ``JAX_PLATFORMS=cpu``, else this process's devices)
+and writes ``BENCH_kernels.json`` (name → us_per_call) at
 the repo root, seeding the perf trajectory across PRs.  ``--smoke --full``
 additionally runs the slow ``rand_lu_n2048_k256`` accuracy-tier rows.
 """
@@ -27,14 +28,17 @@ SMOKE_BANDED_IMPLS = ("pallas_blocked", "pallas_tiled", "pallas_scalar")
 
 
 def _spike_subprocess_row(n: int, bw: int, devices: int) -> float | None:
-    """Time the multi-device SPIKE substitution at the paper shape.
+    """Time the multi-device SPIKE substitution at the paper shape on
+    ``devices`` emulated host devices.
 
-    Runs in a child process with its own ``XLA_FLAGS`` because the host
-    platform's device count is locked at backend init — forcing
+    Runs in a CPU-only child process with its own ``XLA_FLAGS`` because the
+    host platform's device count is locked at backend init — forcing
     ``devices`` host devices in *this* process would change the timing
-    environment of every single-device row above.  Returns seconds per
-    call, or ``None`` when the child fails (row is then omitted and
-    scripts/check.sh skips its gate with a note)."""
+    environment of every single-device row.  :func:`main` starts it before
+    this process imports JAX, so the child never competes with a parent
+    that holds an accelerator.  Returns seconds per call, or ``None`` when
+    the child fails (row is then omitted and scripts/check.sh skips its
+    gate with a note)."""
     import subprocess
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -64,6 +68,7 @@ print(f"SPIKE_US={{t * 1e6:.1f}}")
         out = subprocess.run(
             [sys.executable, "-c", script],
             capture_output=True, text=True, timeout=900, check=True,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
     except (subprocess.SubprocessError, OSError) as e:
         detail = getattr(e, "stderr", "") or ""
@@ -79,7 +84,47 @@ print(f"SPIKE_US={{t * 1e6:.1f}}")
     return None
 
 
-def smoke(out_path: str | None = None, full: bool = False) -> dict[str, float]:
+def _cpu_only() -> bool:
+    """Whether ``$JAX_PLATFORMS`` holds this process to the CPU — known
+    before JAX is imported, so :func:`main` can start the CPU SPIKE child
+    first only where no accelerator is in play."""
+    platforms = [p for p in os.environ.get("JAX_PLATFORMS", "").split(",") if p]
+    return platforms == ["cpu"]
+
+
+def _capable(problem, impls) -> list[str]:
+    """The impls of a shootout this device can run: each backend's registry
+    capability predicate for ``problem`` (kernels Mosaic cannot lower are
+    rejected on TPU).  ``"pallas"`` is the Pallas-only auto alias."""
+    from repro.solvers import registry
+
+    return [i for i in impls if i == "pallas" or
+            registry.get_backend(problem.op, problem.structure, i).supports(problem)]
+
+
+def _spike_mesh_row(n: int, bw: int) -> tuple[int, float] | None:
+    """In-process SPIKE substitution on a mesh of every local device (the
+    real-mesh counterpart of :func:`_spike_subprocess_row`); returns
+    ``(devices, seconds per call)``, or None with fewer than two devices."""
+    import jax
+
+    from repro.core.banded import make_banded_dd
+    from repro.kernels.spike import spike_lu_sharded, spike_solve_sharded
+    from repro.launch.mesh import make_mesh
+    from .common import time_call
+
+    devices = jax.device_count()
+    if devices < 2:
+        return None
+    mesh = make_mesh((devices,), ("model",))
+    arow = make_banded_dd(jax.random.PRNGKey(0), n, bw)
+    b = jax.random.normal(jax.random.PRNGKey(1), (n,))
+    factors = spike_lu_sharded(arow, bw=bw, mesh=mesh)  # untimed, factor-once
+    return devices, time_call(lambda: spike_solve_sharded(factors, b, mesh=mesh), iters=5)
+
+
+def smoke(out_path: str | None = None, full: bool = False,
+          spike_cpu_s: float | None = None) -> dict[str, float]:
     """Fast perf smoke: table2 at small size + per-impl LU kernel timings +
     the sparse (banded) trajectory at paper scale.
 
@@ -115,22 +160,22 @@ def smoke(out_path: str | None = None, full: bool = False) -> dict[str, float]:
         a = make_diagonally_dominant(jax.random.PRNGKey(n), n)
         # round-robin sampling: close races (fused vs its op-identical xla
         # mirror) must not be decided by measurement order / host drift
+        prob = Problem(op="factor", structure="dense", n=n)
         fns = {impl: functools.partial(lambda impl, a: kops.lu(a, impl=impl), impl)
-               for impl in SMOKE_LU_IMPLS}
+               for impl in _capable(prob, SMOKE_LU_IMPLS)}
         times = time_shootout(fns, a, iters=15 if n <= 256 else 5)
-        tune.record(Problem(op="factor", structure="dense", n=n),
-                    {impl: t * 1e6 for impl, t in times.items()})
+        tune.record(prob, {impl: t * 1e6 for impl, t in times.items()})
         for impl, t in times.items():
             rows_us[f"lu_n{n}_{impl}"] = t * 1e6
             emit(f"lu_n{n}_{impl}", t)
 
     nb, bw = SMOKE_BANDED_N, SMOKE_BANDED_BW
     arow = make_banded_dd(jax.random.PRNGKey(0), nb, bw)
+    prob = Problem(op="factor", structure="banded", n=nb, bw=bw)
     fns = {impl: functools.partial(lambda impl, a: kops.banded_lu(a, bw=bw, impl=impl), impl)
-           for impl in SMOKE_BANDED_IMPLS}
+           for impl in _capable(prob, SMOKE_BANDED_IMPLS)}
     banded_lu_times = time_shootout(fns, arow, iters=5)
-    tune.record(Problem(op="factor", structure="banded", n=nb, bw=bw),
-                {impl: t * 1e6 for impl, t in banded_lu_times.items()})
+    tune.record(prob, {impl: t * 1e6 for impl, t in banded_lu_times.items()})
     for impl, t in banded_lu_times.items():
         rows_us[f"banded_lu_n{nb}_{impl}"] = t * 1e6
         emit(f"banded_lu_n{nb}_{impl}", t)
@@ -144,24 +189,34 @@ def smoke(out_path: str | None = None, full: bool = False) -> dict[str, float]:
     # its packed factors; pallas_inverted consumes the enrichments)
     lub = kops.banded_lu(arow, bw=bw, enrich=True)
     b = jax.random.normal(jax.random.PRNGKey(1), (nb,))
+    prob = Problem(op="solve", structure="banded", n=nb, bw=bw, rhs=1)
     fns = {impl: functools.partial(lambda impl, l, r: kops.banded_solve(l, r, bw=bw, impl=impl), impl)
-           for impl in ("pallas", "xla_scalar", "pallas_inverted")}
+           for impl in _capable(prob, ("pallas", "xla_scalar", "pallas_inverted"))}
     banded_solve_times = time_shootout(fns, lub, b, iters=5)
-    tune.record(Problem(op="solve", structure="banded", n=nb, bw=bw, rhs=1),
-                {impl: t * 1e6 for impl, t in banded_solve_times.items()})
+    tune.record(prob, {impl: t * 1e6 for impl, t in banded_solve_times.items()})
     for impl, t in banded_solve_times.items():
         rows_us[f"banded_solve_n{nb}_{impl}"] = t * 1e6
         emit(f"banded_solve_n{nb}_{impl}", t)
     tune.save()  # dispatch decisions now provably follow the committed rows
 
-    # --- multi-device SPIKE split substitution at the same paper shape,
-    # timed under 8 forced host devices in a subprocess (see helper).
-    # scripts/check.sh gates it <= SPIKE_MAX_RATIO x the best single-device
+    # --- multi-device SPIKE split substitution at the same paper shape:
+    # under JAX_PLATFORMS=cpu, the row main() timed under 8 forced host
+    # devices in a CPU child before this process imported JAX; otherwise
+    # in-process on a mesh of this process's devices.  scripts/check.sh
+    # gates the d8 row <= SPIKE_MAX_RATIO x the best single-device
     # substitution above.
-    t = _spike_subprocess_row(nb, bw, devices=8)
-    if t is not None:
-        rows_us[f"banded_solve_n{nb}_spike_d8"] = t * 1e6
-        emit(f"banded_solve_n{nb}_spike_d8", t)
+    if spike_cpu_s is not None:
+        rows_us[f"banded_solve_n{nb}_spike_d8"] = spike_cpu_s * 1e6
+        emit(f"banded_solve_n{nb}_spike_d8", spike_cpu_s)
+    elif not _cpu_only():
+        row = _spike_mesh_row(nb, bw)
+        if row is None:
+            print(f"banded_solve_n{nb}_spike_SKIPPED,0,one_device"
+                  "(set JAX_PLATFORMS=cpu for the 8-host-device row)", file=sys.stderr)
+        else:
+            d, t = row
+            rows_us[f"banded_solve_n{nb}_spike_d{d}"] = t * 1e6
+            emit(f"banded_solve_n{nb}_spike_d{d}", t)
 
     # --- stacked-RHS dense substitution at transfer scale: one n=4096
     # artifact (factored+enriched once, untimed — the factor-once/solve-many
@@ -291,8 +346,17 @@ def main() -> None:
     args = ap.parse_args()
 
     print("name,us_per_call,derived")
+    spike_cpu_s = None
+    if args.smoke and _cpu_only():
+        # the emulated-mesh SPIKE child runs before this process imports
+        # JAX, and only where no accelerator is in play
+        spike_cpu_s = _spike_subprocess_row(SMOKE_BANDED_N, SMOKE_BANDED_BW, devices=8)
+
+    from repro.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     if args.smoke:
-        smoke(full=args.full)
+        smoke(full=args.full, spike_cpu_s=spike_cpu_s)
         return
 
     from . import table1_sparse, table2_dense, table3_transfer, lm_step

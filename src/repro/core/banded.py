@@ -35,7 +35,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .blocked import strip_trsm, strip_utrsm, sub_block_width
+from jax.experimental import pallas as pl
+
+from .blocked import (
+    ValueRef,
+    col_at,
+    dot_f32,
+    row_at,
+    set_rows,
+    strip_trsm,
+    strip_utrsm,
+    sub_block_width,
+)
 
 __all__ = [
     "to_banded",
@@ -49,11 +60,11 @@ __all__ = [
     "band_to_skewed",
     "skewed_to_band",
     "skew_rows",
+    "BANDED_VMEM_MAX_BYTES",
+    "blocked_kernel_takes",
     "skew_pad",
     "band_window_from_slabs",
     "factor_band_window",
-    "band_step_slabs",
-    "band_step_writeback",
     "band_block_step",
     "unit_lower_window_solve",
     "upper_window_solve",
@@ -204,34 +215,31 @@ def pad_band_identity(arow: jax.Array, bw: int, rows_to: int) -> jax.Array:
 def band_to_skewed(ap: jax.Array, bw: int, block: int) -> jax.Array:
     """Re-lay the row-aligned band ``(R, 2bw+1)`` (``R`` a multiple of
     ``block``) into the window-aligned skewed form ``G`` ``(R, C+2bw)``:
-    ``G[i, c] = A[i, k(i) - bw + c]`` with ``k(i) = (i // C)·C``.
+    ``G[i, c] = A[i, k(i) - bw + c]`` with ``k(i) = (i // C)·C``, i.e. row
+    ``i`` of the band shifted right by ``i mod C`` (zero outside).
 
     In this layout the blocked drivers assemble every dense working window
     from two *contiguous static slices* of ``G`` — the per-step gather that
-    a row-aligned shear would need never happens.  The skew itself is the
-    classic flat-reshape trick: shifting row ``r0`` of a block right by
-    ``r0`` is the identity on flattened indices once rows are padded to
-    width ``C+2bw+1``, so the whole conversion is one pad + two reshapes +
-    one slice.  Pure data movement (exact), so it never perturbs bitwise
-    comparisons."""
+    a row-aligned shear would need never happens.  The shift is one
+    ``take_along_axis`` (the flat-reshape form of the same shear took XLA's
+    TPU compiler two minutes at some band heights).  Pure data movement
+    (exact), so it never perturbs bitwise comparisons."""
     r, w = ap.shape
-    c = block
-    gw = c + 2 * bw
-    # rows padded to gw+1: flat index r0·(gw+1) + t  ==  r0·gw + (r0 + t),
-    # i.e. exactly the skewed row-of-gw layout.
-    padded = jnp.pad(ap.reshape(r // c, c, w), ((0, 0), (0, 0), (0, gw + 1 - w)))
-    flat = padded.reshape(r // c, c * (gw + 1))[:, : c * gw]
-    return flat.reshape(r, gw)
+    gw = block + 2 * bw
+    shift = jax.lax.broadcasted_iota(jnp.int32, (r, gw), 0) % block
+    t = jax.lax.broadcasted_iota(jnp.int32, (r, gw), 1) - shift
+    inside = (t >= 0) & (t < w)
+    return jnp.where(inside, jnp.take_along_axis(ap, jnp.clip(t, 0, w - 1), axis=1), 0)
 
 
 def skewed_to_band(g: jax.Array, bw: int, block: int) -> jax.Array:
     """Inverse of :func:`band_to_skewed`: skewed ``(R, C+2bw)`` → row-aligned
-    band ``(R, 2bw+1)`` (the same flat-reshape identity, run backwards)."""
-    r, gw = g.shape
-    c = block
+    band ``(R, 2bw+1)`` (``A[i, t] = G[i, t + i mod C]``)."""
+    r = g.shape[0]
     w = 2 * bw + 1
-    flat = jnp.pad(g.reshape(r // c, c * gw), ((0, 0), (0, c)))
-    return flat.reshape(r // c, c, gw + 1)[:, :, :w].reshape(r, w)
+    shift = jax.lax.broadcasted_iota(jnp.int32, (r, w), 0) % block
+    t = jax.lax.broadcasted_iota(jnp.int32, (r, w), 1)
+    return jnp.take_along_axis(g, t + shift, axis=1)
 
 
 def band_window_from_slabs(own: jax.Array, carry: jax.Array, bw: int) -> jax.Array:
@@ -240,37 +248,66 @@ def band_window_from_slabs(own: jax.Array, carry: jax.Array, bw: int) -> jax.Arr
     rows) and ``carry`` (the next block's first ``bw`` rows — ``(bw, 2bw)``
     when ``C ≥ bw``, ``(bw, C+bw)`` sliced at column ``bw-C`` otherwise)."""
     c = own.shape[0]
-    top = own[:, bw:]  # window columns 0..C+bw-1 of the step's own rows
-    if c >= bw:
+    top = own[:, bw : c + 2 * bw]  # window columns 0..C+bw-1 of the step's own rows
+    if c > bw:
         bot = jnp.concatenate([jnp.zeros((bw, c - bw), own.dtype), carry], axis=1)
     else:
         bot = carry
     return jnp.concatenate([top, bot], axis=0)
 
 
+def _band_pivot_step(size: int, bw: int):
+    """One bi-vector elimination on a ``(size, size)`` window value,
+    confined by masks to the ``(bw+1, bw+1)`` block the band can reach from
+    pivot ``p``: scale the L column by the pivot, subtract the outer
+    product.  Masks instead of a traced ``dynamic_slice`` of that block,
+    which Mosaic does not lower; every masked-in value is computed by the
+    same scalar ops as the sliced form, so the result is the same bits."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (size, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, size), 1)
+
+    def piv(p, wnd):
+        prow = row_at(wnd, p)
+        pivot = col_at(prow, p)
+        in_r = (rows > p) & (rows <= p + bw)
+        in_c = (cols > p) & (cols <= p + bw)
+        l_col = jnp.where(in_r, col_at(wnd, p) / pivot, 0.0)
+        u_row = jnp.where(in_c, prow, 0.0)
+        wnd = jnp.where(in_r & in_c, wnd - l_col * u_row, wnd)  # rank-1 Schur update
+        return jnp.where(in_r & (cols == p), l_col, wnd)
+
+    return piv
+
+
+def _put_square(window: jax.Array, sub: jax.Array, q: int) -> jax.Array:
+    """``window`` with its ``[q:e, q:e]`` square replaced by ``sub`` (static
+    offsets, assembled by concatenation)."""
+    e = q + sub.shape[0]
+    mid = [window[q:e, :q], sub, window[q:e, e:]]
+    mid = jnp.concatenate([m for m in mid if m.shape[1]], axis=1)
+    return set_rows(window, mid, q)
+
+
 def factor_band_window(window: jax.Array, npiv: int, bw: int) -> jax.Array:
     """No-pivot LU of the dense band window ``(npiv+bw, npiv+bw)``, retiring
-    pivots ``0..npiv-1``.  Each bi-vector elimination is *confined to the
-    ``(bw+1, bw+1)`` sub-block the band can reach* — the paper's naturally
+    pivots ``0..npiv-1``.  Each bi-vector elimination is confined to the
+    ``(bw+1, bw+1)`` sub-block the band can reach — the paper's naturally
     equalized unit: every step is one identical fixed-shape fused update
-    (scale the L column by the pivot, subtract the outer product), with no
-    masking waste on the ``(npiv+bw)²`` window.  Collectively the ``npiv``
+    (scale the L column by the pivot, subtract the outer product).
+    Pivots run in static chunks of ``k``: chunk ``q`` only reaches the
+    ``[q, q+k+bw)`` square, so each step's masked update covers
+    ``(k+bw)²`` instead of the whole window.  Collectively the ``npiv``
     steps apply the block step's rank-``npiv`` Schur update to the
     ``(bw, bw)`` carry corner.  Shared verbatim by the Pallas kernels and
     the pure-jnp mirror (bitwise contract)."""
-
-    def piv(p, wnd):
-        blk = jax.lax.dynamic_slice(wnd, (p, p), (bw + 1, bw + 1))
-        pivot = blk[:1, :1]
-        l_col = blk[:, :1] / pivot
-        u_row = blk[:1, :]
-        upd = blk - l_col * u_row  # rank-1 Schur update on the reachable block
-        blk = jnp.concatenate(
-            [u_row, jnp.concatenate([l_col[1:], upd[1:, 1:]], axis=1)], axis=0
-        )
-        return jax.lax.dynamic_update_slice(wnd, blk, (p, p))
-
-    return jax.lax.fori_loop(0, npiv, piv, window)
+    k = min(npiv, max(8, min(64, bw // 2) // 8 * 8))
+    for q in range(0, npiv, k):
+        kq = min(k, npiv - q)
+        e = q + kq + bw
+        sub = window[q:e, q:e]
+        sub = jax.lax.fori_loop(0, kq, _band_pivot_step(e - q, bw), sub)
+        window = _put_square(window, sub, q)
+    return window
 
 
 def unit_lower_window_solve(lwin: jax.Array, y: jax.Array, bw: int) -> jax.Array:
@@ -282,14 +319,12 @@ def unit_lower_window_solve(lwin: jax.Array, y: jax.Array, bw: int) -> jax.Array
     c2 = sub_block_width(c)
     for j in range(0, c, c2):
         strip = strip_trsm(lwin[j : j + c2, j : j + c2], y[j : j + c2, :])
-        y = jax.lax.dynamic_update_slice(y, strip, (j, 0))
+        y = set_rows(y, strip, j)
         hr = min(bw, c - j - c2)
         if hr:
             lpart = lwin[j + c2 : j + c2 + hr, j : j + c2]
-            tail = y[j + c2 : j + c2 + hr, :] - jnp.dot(
-                lpart, strip, preferred_element_type=jnp.float32
-            ).astype(y.dtype)
-            y = jax.lax.dynamic_update_slice(y, tail, (j + c2, 0))
+            tail = y[j + c2 : j + c2 + hr, :] - dot_f32(lpart, strip).astype(y.dtype)
+            y = set_rows(y, tail, j + c2)
     return y
 
 
@@ -301,14 +336,12 @@ def upper_window_solve(uwin: jax.Array, x: jax.Array, bw: int) -> jax.Array:
     c2 = sub_block_width(c)
     for j in range(c - c2, -1, -c2):
         strip = strip_utrsm(uwin[j : j + c2, j : j + c2], x[j : j + c2, :])
-        x = jax.lax.dynamic_update_slice(x, strip, (j, 0))
+        x = set_rows(x, strip, j)
         hr = min(bw, j)
         if hr:
             upart = uwin[j - hr : j, j : j + c2]
-            head = x[j - hr : j, :] - jnp.dot(
-                upart, strip, preferred_element_type=jnp.float32
-            ).astype(x.dtype)
-            x = jax.lax.dynamic_update_slice(x, head, (j - hr, 0))
+            head = x[j - hr : j, :] - dot_f32(upart, strip).astype(x.dtype)
+            x = set_rows(x, head, j - hr)
     return x
 
 
@@ -323,6 +356,23 @@ def skew_rows(n: int, bw: int, block: int) -> int:
     return (s + max(1, -(-bw // block))) * block
 
 
+# Above this many skewed-band bytes the VMEM-resident blocked kernel gives
+# way to the HBM-streaming tiled kernel (the blocked kernel holds the skewed
+# band twice — in and out — in VMEM).
+BANDED_VMEM_MAX_BYTES = 6 * 2**20
+
+
+def blocked_kernel_takes(n: int, bw: int, block: int | None, itemsize: int, *,
+                         compiled: bool) -> bool:
+    """Whether the VMEM-resident blocked band kernel takes this band rather
+    than the tiled one: the skewed band within :data:`BANDED_VMEM_MAX_BYTES`
+    and, when Mosaic compiles it, the traced block offsets sublane-aligned
+    (``C % 8 == 0``).  One rule for the registry and SPIKE's local factor."""
+    c = band_block_size(n, bw, block)
+    fits = skew_rows(n, bw, c) * (c + 2 * bw) * itemsize <= BANDED_VMEM_MAX_BYTES
+    return fits and (not compiled or c % 8 == 0)
+
+
 def skew_pad(arow: jax.Array, bw: int, block: int) -> tuple[jax.Array, int]:
     """Identity-pad the band to :func:`skew_rows` rows and re-lay it into
     the skewed form the blocked drivers consume.  Returns ``(G, num_steps)``.
@@ -333,37 +383,26 @@ def skew_pad(arow: jax.Array, bw: int, block: int) -> tuple[jax.Array, int]:
     return band_to_skewed(ap, bw, block), -(-n // block)
 
 
-def band_step_slabs(g: jax.Array, k, *, block: int, bw: int):
-    """Slice one block step's (own, carry) slabs out of the skewed band at
-    row offset ``k`` (traced or static).  Shared kernel/mirror code."""
+def _carry_cols(block: int, bw: int) -> tuple[int, int]:
+    """Column span ``(start, width)`` of a step's ``bw`` carry rows in the
+    skewed band: ``(0, 2bw)`` when ``C ≥ bw``, ``(bw-C, C+bw)`` otherwise."""
+    return (0, 2 * bw) if block >= bw else (bw - block, block + bw)
+
+
+def band_block_step(g, k, *, block: int, bw: int) -> None:
+    """One blocked band LU step on the skewed band *ref* ``g``, in place:
+    read the step's own ``C`` rows and the next block's ``bw`` carry rows
+    (row offset ``k``, traced or static), assemble the dense window, retire
+    ``C`` pivots, write both slabs back — the own rows are final, the carry
+    rows flow into the next step.  Shared verbatim by the Pallas kernels
+    and the pure-jnp mirror (which runs it on a :class:`ValueRef`)."""
     c = block
-    gw = c + 2 * bw
-    own = jax.lax.dynamic_slice(g, (k, 0), (c, gw))
-    if c >= bw:
-        carry = jax.lax.dynamic_slice(g, (k + c, 0), (bw, 2 * bw))
-    else:
-        carry = jax.lax.dynamic_slice(g, (k + c, bw - c), (bw, c + bw))
-    return own, carry
-
-
-def band_step_writeback(g: jax.Array, window: jax.Array, k, *, block: int, bw: int):
-    """Write a factored window back into the skewed band: the step's own
-    ``C`` rows are final; its ``bw`` carry rows flow into the next block's
-    leading columns.  Shared kernel/mirror code."""
-    c = block
-    g = jax.lax.dynamic_update_slice(g, window[:c, :], (k, bw))
-    if c >= bw:
-        return jax.lax.dynamic_update_slice(g, window[c:, c - bw :], (k + c, 0))
-    return jax.lax.dynamic_update_slice(g, window[c:, :], (k + c, bw - c))
-
-
-def band_block_step(g: jax.Array, k, *, block: int, bw: int) -> jax.Array:
-    """One blocked band LU step on the skewed band: assemble the dense
-    window from two static slices, retire ``C`` pivots, write back.  Shared
-    verbatim by the Pallas kernels and the pure-jnp mirror."""
-    own, carry = band_step_slabs(g, k, block=block, bw=bw)
-    window = factor_band_window(band_window_from_slabs(own, carry, bw), block, bw)
-    return band_step_writeback(g, window, k, block=block, bw=bw)
+    c0, cw = _carry_cols(c, bw)
+    own = g[pl.ds(k, c), pl.ds(0, c + 2 * bw)]  # the ref may be lane-padded wider
+    carry = g[pl.ds(k + c, bw), pl.ds(c0, cw)]
+    window = factor_band_window(band_window_from_slabs(own, carry, bw), c, bw)
+    g[pl.ds(k, c), pl.ds(bw, c + bw)] = window[:c, :]
+    g[pl.ds(k + c, bw), pl.ds(c0, cw)] = window[c:, c + bw - cw :]
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "block"))
@@ -376,9 +415,11 @@ def banded_lu_blocked(arow: jax.Array, *, bw: int, block: int | None = None) -> 
     n = arow.shape[0]
     c = band_block_size(n, bw, block)
     g, s = skew_pad(arow, bw, c)
+
+    ref = ValueRef(g)
     for i in range(s):
-        g = band_block_step(g, i * c, block=c, bw=bw)
-    return skewed_to_band(g, bw, c)[:n]
+        band_block_step(ref, i * c, block=c, bw=bw)
+    return skewed_to_band(ref.value, bw, c)[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "block"))
@@ -407,16 +448,14 @@ def banded_solve_blocked(
     for i in range(s):
         k = i * c
         f = g[k : k + c]
-        yblk = xp[bw + k : bw + k + c] - jnp.dot(
-            f[:, :bw], xp[k : k + bw], preferred_element_type=jnp.float32
-        ).astype(xp.dtype)
+        yblk = xp[bw + k : bw + k + c] - dot_f32(f[:, :bw], xp[k : k + bw]).astype(xp.dtype)
         yblk = unit_lower_window_solve(f[:, bw : bw + c], yblk, bw)
         xp = jax.lax.dynamic_update_slice(xp, yblk, (bw + k, 0))
     for i in range(s - 1, -1, -1):
         k = i * c
         f = g[k : k + c]
-        xblk = xp[bw + k : bw + k + c] - jnp.dot(
-            f[:, bw + c :], xp[bw + k + c : bw + k + c + bw], preferred_element_type=jnp.float32
+        xblk = xp[bw + k : bw + k + c] - dot_f32(
+            f[:, bw + c :], xp[bw + k + c : bw + k + c + bw]
         ).astype(xp.dtype)
         xblk = upper_window_solve(f[:, bw : bw + c], xblk, bw)
         xp = jax.lax.dynamic_update_slice(xp, xblk, (bw + k, 0))

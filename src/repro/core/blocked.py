@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from .solve import unit_lower_solve_packed
 
@@ -27,9 +28,15 @@ __all__ = [
     "fused_blocked_lu",
     "fused_lu_steps",
     "fused_block_size",
+    "FUSED_VMEM_MAX_N",
     "sub_block_width",
     "strip_trsm",
     "strip_utrsm",
+    "row_at",
+    "col_at",
+    "set_rows",
+    "dot_f32",
+    "ValueRef",
     "factor_diag_strip",
     "solve_below_strip",
     "pad_identity_tail",
@@ -58,19 +65,77 @@ def pad_identity_tail(a: jax.Array, n_to: int) -> jax.Array:
     return jnp.zeros((n_to, n_to), a.dtype).at[:n, :n].set(a).at[pad_ix, pad_ix].set(one)
 
 
+# Mosaic (the TPU Pallas compiler) lowers neither value-level
+# ``dynamic_slice`` nor ``dynamic_update_slice``, so the step bodies below
+# read a traced row/column of a value with an iota mask and a reduction and
+# write one back with a masked select.  Exactly one term of each reduction
+# is non-zero, so the result equals the old slice bit for bit.
+def row_at(x: jax.Array, k) -> jax.Array:
+    """Row ``k`` (traced or static) of a 2-D value, as ``(1, w)``."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.sum(jnp.where(rows == k, x, 0), axis=0, keepdims=True)
+
+
+def col_at(x: jax.Array, k) -> jax.Array:
+    """Column ``k`` (traced or static) of a 2-D value, as ``(m, 1)``."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(cols == k, x, 0), axis=1, keepdims=True)
+
+
+def set_rows(x: jax.Array, piece: jax.Array, r0: int) -> jax.Array:
+    """``x`` with rows ``r0 : r0 + piece.shape[0]`` replaced (static offset;
+    a concatenation, which Mosaic lowers where ``dynamic_update_slice``
+    does not)."""
+    parts = [x[:r0], piece, x[r0 + piece.shape[0]:]]
+    return jnp.concatenate([p for p in parts if p.shape[0]], axis=0)
+
+
+class ValueRef:
+    """Ref-style view of a (possibly traced) array for the pure-jnp mirrors.
+
+    The shared step bodies are written against Pallas refs (``ref[i:j, k:l]``
+    reads and ``ref[...] = v`` writes, all offsets static), which is what
+    Mosaic lowers.  The mirrors hand the same body this wrapper instead:
+    reads slice ``value``, writes rebind it through ``.at[].set``.  Unlike
+    ``pl.run_state`` it composes with ``jax.vmap`` (the batched mirrors)."""
+
+    def __init__(self, value: jax.Array):
+        self.value = value
+
+    @staticmethod
+    def _index(idx):
+        def one(i):
+            if isinstance(i, pl.Slice):  # pl.ds(start, size) with a static start
+                return slice(int(i.start), int(i.start) + i.size)
+            return i
+
+        return tuple(one(i) for i in idx) if isinstance(idx, tuple) else one(idx)
+
+    def __getitem__(self, idx):
+        return self.value[self._index(idx)]
+
+    def __setitem__(self, idx, v):
+        self.value = self.value.at[self._index(idx)].set(v)
+
+
+def dot_f32(a: jax.Array, b: jax.Array) -> jax.Array:
+    """f32-accurate GEMM for the exact tier: on TPU a DEFAULT-precision f32
+    dot runs in bf16 passes, so every solver dot states HIGHEST."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def strip_trsm(ldiag: jax.Array, rhs: jax.Array) -> jax.Array:
     """Unit-lower solve of a ``(C2, w)`` strip against the ``(C2, C2)``
     diagonal block, as a short sequential masked-axpy recurrence on an array
     carry.  Shared verbatim by the megakernel and its mirror — both sides
     trace this exact jaxpr, so their bitwise equality holds by construction."""
     c2 = ldiag.shape[0]
-    w = rhs.shape[1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (c2, 1), 0)
 
     def body(k, u):
-        lk = jnp.where(rows > k, jax.lax.dynamic_slice(ldiag, (0, k), (c2, 1)), 0.0)
-        uk = jax.lax.dynamic_slice(u, (k, 0), (1, w))
-        return u - lk * uk
+        lk = jnp.where(rows > k, col_at(ldiag, k), 0.0)
+        return u - lk * row_at(u, k)
 
     return jax.lax.fori_loop(0, c2 - 1, body, rhs)
 
@@ -82,15 +147,15 @@ def strip_utrsm(udiag: jax.Array, rhs: jax.Array) -> jax.Array:
     :func:`strip_trsm`.  Shared verbatim by the banded solve kernel and its
     pure-jnp mirror, so their bitwise equality holds by construction."""
     c2 = udiag.shape[0]
-    w = rhs.shape[1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (c2, 1), 0)
 
     def body(kk, x):
         k = c2 - 1 - kk
-        pivot = jax.lax.dynamic_slice(udiag, (k, k), (1, 1))
-        xk = jax.lax.dynamic_slice(x, (k, 0), (1, w)) / pivot
-        x = jax.lax.dynamic_update_slice(x, xk, (k, 0))
-        uk = jnp.where(rows < k, jax.lax.dynamic_slice(udiag, (0, k), (c2, 1)), 0.0)
+        ucol = col_at(udiag, k)
+        pivot = row_at(ucol, k)
+        xk = row_at(x, k) / pivot
+        x = jnp.where(rows == k, xk, x)
+        uk = jnp.where(rows < k, ucol, 0.0)
         return x - uk * xk
 
     return jax.lax.fori_loop(0, c2, body, rhs)
@@ -105,12 +170,13 @@ def factor_diag_strip(dblk: jax.Array, j: int) -> jax.Array:
     cols_c2 = jax.lax.broadcasted_iota(jnp.int32, (1, c2), 1)
 
     def dstep(k, d):
-        piv = jax.lax.dynamic_slice(d, (j + k, k), (1, 1))
-        urow = jnp.where(cols_c2 > k, jax.lax.dynamic_slice(d, (j + k, 0), (1, c2)), 0.0)
-        colb = jax.lax.dynamic_slice(d, (0, k), (b, 1))
+        prow = row_at(d, j + k)
+        piv = col_at(prow, k)
+        urow = jnp.where(cols_c2 > k, prow, 0.0)
+        colb = col_at(d, k)
         lb = jnp.where(rows_b > j + k, colb / piv, 0.0)
         d = d - lb * urow
-        return jax.lax.dynamic_update_slice(d, jnp.where(rows_b > j + k, lb, colb), (0, k))
+        return jnp.where(cols_c2 == k, jnp.where(rows_b > j + k, lb, colb), d)
 
     return jax.lax.fori_loop(0, c2, dstep, dblk)
 
@@ -121,41 +187,58 @@ def solve_below_strip(diag: jax.Array, strip: jax.Array, j: int) -> jax.Array:
     (pivot row ``j+k`` of ``diag`` is final by its iteration), so this is
     bitwise-identical to eliminating column-by-column.  Shared kernel/mirror
     code."""
-    b, c2 = strip.shape
+    c2 = strip.shape[1]
     cols_c2 = jax.lax.broadcasted_iota(jnp.int32, (1, c2), 1)
 
     def bstep(k, st):
-        piv = jax.lax.dynamic_slice(diag, (j + k, k), (1, 1))
-        urow = jnp.where(cols_c2 > k, jax.lax.dynamic_slice(diag, (j + k, 0), (1, c2)), 0.0)
-        colb = jax.lax.dynamic_slice(st, (0, k), (b, 1))
-        lb = colb / piv  # every row is below the pivot here
+        prow = row_at(diag, j + k)
+        piv = col_at(prow, k)
+        urow = jnp.where(cols_c2 > k, prow, 0.0)
+        lb = col_at(st, k) / piv  # every row is below the pivot here
         st = st - lb * urow
-        return jax.lax.dynamic_update_slice(st, lb, (0, k))
+        return jnp.where(cols_c2 == k, lb, st)
 
     return jax.lax.fori_loop(0, c2, bstep, strip)
 
 
-def fused_block_size(n: int, block: int, *, vmem_budget_bytes: int = 12 * 2**20) -> int:
+# Padded orders at or below this run the fused LU on one VMEM-resident block
+# (static ref slices, no alignment constraint); above it the matrix streams
+# from HBM in (·, B) column slabs, which Mosaic slices only at multiples of
+# the 128-lane tile.  Shared by the megakernel and the mirror.
+FUSED_VMEM_MAX_N = 512
+_LANE = 128
+
+
+def fused_block_size(n: int, block: int, *, vmem_budget_bytes: int = 40 * 2**20) -> int:
     """Effective block size of the fused LU driver for an (n, n) matrix.
 
     Shared by the megakernel and its mirror (same reasons as
-    :func:`sub_block_width`).  Two adjustments over ``min(block, n)``:
+    :func:`sub_block_width`).  Adjustments over ``min(block, n)``:
 
     * **padding**: the fused driver pads n up to ``S·B``; for n just above a
       block multiple (n=257, block=256) that nearly doubles the matrix.  At
       the same step count ``S``, ``B = ceil(n/S)`` rounded up to a 32
-      multiple gives minimal padding — pick whichever candidate pads less.
-    * **VMEM**: the kernel holds three (N, B) fp32 scratch slabs; halve B
-      until they fit the budget so the default path compiles on real TPUs
-      for large n (e.g. n=8000 → B=128) instead of overflowing VMEM.
+      multiple (a 128 multiple once the padded order streams from HBM)
+      gives minimal padding — pick whichever candidate pads less.
+    * **lanes**: on the HBM-streaming path (padded order above
+      ``FUSED_VMEM_MAX_N``) B stays a multiple of 128 so every column-slab
+      DMA is lane-aligned on TPU.
+    * **VMEM**: the kernel holds three (N, B) fp32 scratch slabs; B drops
+      to the next lower 128 multiple until they fit the budget (n=16384 →
+      B=128, 25 MB).
     """
     B = min(block, n)
     S = -(-n // B)
-    balanced = min(block, -(-(-(-n // S)) // 32) * 32)  # ceil(n/S) up to a 32-multiple
+    per = -(-n // S)
+    balanced = min(block, -(-per // 32) * 32)
+    if -(-n // balanced) * balanced > FUSED_VMEM_MAX_N:
+        balanced = min(max(block, _LANE), -(-per // _LANE) * _LANE)
     if balanced >= 32 and -(-n // balanced) * balanced < S * B:
         B = balanced
-    while B > 32 and 3 * (-(-n // B) * B) * B * 4 > vmem_budget_bytes:
-        B = max(32, B // 2)
+    if -(-n // B) * B > FUSED_VMEM_MAX_N and B % _LANE:
+        B = -(-B // _LANE) * _LANE
+    while B > _LANE and 3 * (-(-n // B) * B) * B * 4 > vmem_budget_bytes:
+        B = max(_LANE, (B // 2) // _LANE * _LANE)
     return B
 
 
@@ -195,11 +278,12 @@ def blocked_lu(a: jax.Array, *, block: int = 256) -> jax.Array:
     return a
 
 
-def fused_lu_steps(a: jax.Array, *, block: int, num_steps: int) -> jax.Array:
-    """Value-level body of the fused blocked LU on an already-padded
-    ``(S·B, S·B)`` array: two-level panel factorization + trailing-tile
-    trsm/update per step.  Shared verbatim by the pure-jnp mirror
-    (:func:`fused_blocked_lu`) and the small-n VMEM megakernel
+def fused_lu_steps(a, *, block: int, num_steps: int) -> None:
+    """Body of the fused blocked LU on an already-padded ``(S·B, S·B)``
+    *ref*, factored in place: two-level panel factorization +
+    trailing-tile trsm/update per step, every slice static.  Shared verbatim
+    by the pure-jnp mirror (:func:`fused_blocked_lu`, which runs it on a
+    :class:`ValueRef`) and the small-n VMEM megakernel
     (:func:`repro.kernels.ebv_lu.lu_fused`) — both trace these exact ops,
     which is what makes their packed factors bitwise-identical."""
     B, S = block, num_steps
@@ -212,57 +296,48 @@ def fused_lu_steps(a: jax.Array, *, block: int, num_steps: int) -> jax.Array:
             w = B - j - C2
 
             # (1) bi-vectorized factorization of the diagonal-block strip
-            # (dynamic_update_slice, not .at[].set: when the strip covers the
-            # whole array — S == 1 and C2 == B, i.e. n ≤ 32 — the full-slice
-            # scatter lowers with an empty int32[0] index constant that the
-            # Pallas kernel tracer rejects as a captured constant)
             diag = factor_diag_strip(a[base : base + B, r0 : r0 + C2], j)
-            a = jax.lax.dynamic_update_slice(a, diag, (base, r0))
+            a[base : base + B, r0 : r0 + C2] = diag
 
             # (2) unit-lower trsm: U rows of the strip vs the remaining cols
             if w:
                 u = strip_trsm(diag[j : j + C2, :], a[r0 : r0 + C2, r0 + C2 : base + B])
-                a = a.at[r0 : r0 + C2, r0 + C2 : base + B].set(u)
+                a[r0 : r0 + C2, r0 + C2 : base + B] = u
                 lpart = diag[j + C2 :, :]
                 blk = a[r0 + C2 : base + B, r0 + C2 : base + B]
-                a = a.at[r0 + C2 : base + B, r0 + C2 : base + B].set(
-                    (blk - jnp.dot(lpart, u, preferred_element_type=jnp.float32)).astype(a.dtype)
-                )
+                a[r0 + C2 : base + B, r0 + C2 : base + B] = (
+                    blk - dot_f32(lpart, u)
+                ).astype(blk.dtype)
 
             # (3) row blocks below: right-solve multipliers + GEMM retirement
             for r in range(s + 1, S):
                 off = r * B
                 strip = solve_below_strip(diag, a[off : off + B, r0 : r0 + C2], j)
-                a = a.at[off : off + B, r0 : r0 + C2].set(strip)
+                a[off : off + B, r0 : r0 + C2] = strip
                 if w:
                     blkr = a[off : off + B, r0 + C2 : base + B]
-                    a = a.at[off : off + B, r0 + C2 : base + B].set(
-                        (blkr - jnp.dot(strip, u, preferred_element_type=jnp.float32)).astype(a.dtype)
-                    )
+                    a[off : off + B, r0 + C2 : base + B] = (
+                        blkr - dot_f32(strip, u)
+                    ).astype(blkr.dtype)
         # ---- trailing tiles: two-level trsm + rank-B update per row block
         for t in range(s + 1, S):
             tb = t * B
-            y = a[base : base + B, tb : tb + B]
             for j in range(0, B, C2):
                 r0 = base + j
-                strip = strip_trsm(a[r0 : r0 + C2, r0 : r0 + C2], y[j : j + C2, :])
-                y = jax.lax.dynamic_update_slice(y, strip, (j, 0))
-                w = B - j - C2
-                if w:
+                strip = strip_trsm(a[r0 : r0 + C2, r0 : r0 + C2], a[r0 : r0 + C2, tb : tb + B])
+                a[r0 : r0 + C2, tb : tb + B] = strip
+                if B - j - C2:
                     lpart = a[r0 + C2 : base + B, r0 : r0 + C2]
-                    tail = (
-                        y[j + C2 :, :] - jnp.dot(lpart, strip, preferred_element_type=jnp.float32)
-                    ).astype(y.dtype)
-                    y = jax.lax.dynamic_update_slice(y, tail, (j + C2, 0))
-            a = a.at[base : base + B, tb : tb + B].set(y)
+                    tail = a[r0 + C2 : base + B, tb : tb + B]
+                    a[r0 + C2 : base + B, tb : tb + B] = (
+                        tail - dot_f32(lpart, strip)
+                    ).astype(tail.dtype)
+            y = a[base : base + B, tb : tb + B]
             for r in range(s + 1, S):
                 off = r * B
                 lblk = a[off : off + B, base : base + B]
                 blk = a[off : off + B, tb : tb + B]
-                a = a.at[off : off + B, tb : tb + B].set(
-                    (blk - jnp.dot(lblk, y, preferred_element_type=jnp.float32)).astype(a.dtype)
-                )
-    return a
+                a[off : off + B, tb : tb + B] = (blk - dot_f32(lblk, y)).astype(blk.dtype)
 
 
 def fused_blocked_lu(a: jax.Array, *, block: int = 256) -> jax.Array:
@@ -282,7 +357,9 @@ def fused_blocked_lu(a: jax.Array, *, block: int = 256) -> jax.Array:
     S = -(-n // B)
     N = S * B
     a = pad_identity_tail(a, N)
-    a = fused_lu_steps(a, block=B, num_steps=S)
+    ref = ValueRef(a)
+    fused_lu_steps(ref, block=B, num_steps=S)
+    a = ref.value
     return a[:n, :n] if N != n else a
 
 

@@ -58,6 +58,10 @@ from .banded import band_block_size, band_to_skewed, pad_band_identity
 from .blocked import pad_identity_tail
 from .health import FactorHealth
 
+# Exact-tier GEMMs state f32 precision: a DEFAULT-precision f32 dot runs in
+# bf16 passes on TPU.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "Factorization",
     "dense_block_inverses",
@@ -141,8 +145,8 @@ def banded_block_inverses(
     s = g.shape[-2] // c
     f = g.reshape(s, c, gw)
     linv, uinv = _packed_block_inverses(f[:, :, bw : bw + c])
-    tlo = jnp.matmul(linv, f[:, :, :bw], preferred_element_type=jnp.float32).astype(g.dtype)
-    tup = jnp.matmul(uinv, f[:, :, bw + c :], preferred_element_type=jnp.float32).astype(g.dtype)
+    tlo = jnp.matmul(linv, f[:, :, :bw], precision=_HIGHEST, preferred_element_type=jnp.float32).astype(g.dtype)
+    tup = jnp.matmul(uinv, f[:, :, bw + c :], precision=_HIGHEST, preferred_element_type=jnp.float32).astype(g.dtype)
     return linv, uinv, tlo, tup
 
 
@@ -161,13 +165,13 @@ def inverted_dense_sweeps(read_tile, read_linv, read_uinv, x, *, num_steps: int,
     def fwd(i, x):
         yi = jnp.dot(
             read_linv(i), jax.lax.dynamic_slice(x, (i * b, 0), (b, rt)),
-            preferred_element_type=jnp.float32,
+            precision=_HIGHEST, preferred_element_type=jnp.float32,
         ).astype(x.dtype)
         x = jax.lax.dynamic_update_slice(x, yi, (i * b, 0))
 
         def off(r, x):
             blk = jax.lax.dynamic_slice(x, (r * b, 0), (b, rt)) - jnp.dot(
-                read_tile(r, i), yi, preferred_element_type=jnp.float32
+                read_tile(r, i), yi, precision=_HIGHEST, preferred_element_type=jnp.float32
             ).astype(x.dtype)
             return jax.lax.dynamic_update_slice(x, blk, (r * b, 0))
 
@@ -179,13 +183,13 @@ def inverted_dense_sweeps(read_tile, read_linv, read_uinv, x, *, num_steps: int,
         i = s - 1 - jj
         xi = jnp.dot(
             read_uinv(i), jax.lax.dynamic_slice(x, (i * b, 0), (b, rt)),
-            preferred_element_type=jnp.float32,
+            precision=_HIGHEST, preferred_element_type=jnp.float32,
         ).astype(x.dtype)
         x = jax.lax.dynamic_update_slice(x, xi, (i * b, 0))
 
         def off(r, x):
             blk = jax.lax.dynamic_slice(x, (r * b, 0), (b, rt)) - jnp.dot(
-                read_tile(r, i), xi, preferred_element_type=jnp.float32
+                read_tile(r, i), xi, precision=_HIGHEST, preferred_element_type=jnp.float32
             ).astype(x.dtype)
             return jax.lax.dynamic_update_slice(x, blk, (r * b, 0))
 
@@ -204,8 +208,8 @@ def _affine_scan(a: jax.Array, b: jax.Array) -> jax.Array:
         a_lo, b_lo = lo
         a_hi, b_hi = hi
         return (
-            jnp.matmul(a_hi, a_lo, preferred_element_type=jnp.float32).astype(a_lo.dtype),
-            jnp.matmul(a_hi, b_lo, preferred_element_type=jnp.float32).astype(b_lo.dtype)
+            jnp.matmul(a_hi, a_lo, precision=_HIGHEST, preferred_element_type=jnp.float32).astype(a_lo.dtype),
+            jnp.matmul(a_hi, b_lo, precision=_HIGHEST, preferred_element_type=jnp.float32).astype(b_lo.dtype)
             + b_hi,
         )
 
@@ -233,17 +237,17 @@ def inverted_band_sweeps(
     m = xb.shape[-1]
     zero = jnp.zeros((1, bw, m), xb.dtype)
 
-    z = jnp.matmul(linv, xb, preferred_element_type=jnp.float32).astype(xb.dtype)
+    z = jnp.matmul(linv, xb, precision=_HIGHEST, preferred_element_type=jnp.float32).astype(xb.dtype)
     ytail = _affine_scan(-tlo[:, c - bw :, :], z[:, c - bw :, :])
     prev = jnp.concatenate([zero, ytail[:-1]], axis=0)
-    y = z - jnp.matmul(tlo, prev, preferred_element_type=jnp.float32).astype(xb.dtype)
+    y = z - jnp.matmul(tlo, prev, precision=_HIGHEST, preferred_element_type=jnp.float32).astype(xb.dtype)
 
-    w = jnp.matmul(uinv, y, preferred_element_type=jnp.float32).astype(xb.dtype)
+    w = jnp.matmul(uinv, y, precision=_HIGHEST, preferred_element_type=jnp.float32).astype(xb.dtype)
     xhead = jnp.flip(
         _affine_scan(-jnp.flip(tup[:, :bw, :], 0), jnp.flip(w[:, :bw, :], 0)), 0
     )
     nxt = jnp.concatenate([xhead[1:], zero], axis=0)
-    return w - jnp.matmul(tup, nxt, preferred_element_type=jnp.float32).astype(xb.dtype)
+    return w - jnp.matmul(tup, nxt, precision=_HIGHEST, preferred_element_type=jnp.float32).astype(xb.dtype)
 
 
 def equalized_rhs_tile(m: int, rhs_tile: int) -> int:

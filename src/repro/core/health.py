@@ -202,5 +202,6 @@ def relative_residual(a, b, x, *, bw: int = 0) -> jax.Array:
     a32 = jnp.asarray(a, jnp.float32)
     b32 = jnp.asarray(b, jnp.float32)
     x32 = jnp.asarray(x, jnp.float32)
-    ax = banded_matvec(a32, x32, bw=bw) if bw else a32 @ x32
+    ax = (banded_matvec(a32, x32, bw=bw) if bw
+          else jnp.matmul(a32, x32, precision=jax.lax.Precision.HIGHEST))
     return jnp.linalg.norm(b32 - ax) / jnp.maximum(jnp.linalg.norm(b32), _TINY)

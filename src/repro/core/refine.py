@@ -77,7 +77,7 @@ def iterative_refinement(
     bnorm = jnp.maximum(jnp.linalg.norm(b32), jnp.float32(1e-30))
 
     def resid_norm(x):
-        return jnp.linalg.norm(b32 - a32 @ x)
+        return jnp.linalg.norm(b32 - jnp.matmul(a32, x, precision=jax.lax.Precision.HIGHEST))
 
     def cond(carry):
         x, rn, it = carry
@@ -85,7 +85,7 @@ def iterative_refinement(
 
     def body(carry):
         x, _, it = carry
-        r = b32 - a32 @ x
+        r = b32 - jnp.matmul(a32, x, precision=jax.lax.Precision.HIGHEST)
         x = x + solve_fn(r).astype(jnp.float32)
         return (x, resid_norm(x), it + 1)
 

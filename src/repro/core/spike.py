@@ -244,7 +244,9 @@ def spike_recover(factors: SpikeFactors, g: jax.Array, tips: jax.Array) -> jax.A
     xt, xb = t[:, 0], t[:, 1]
     prev_xb = jnp.concatenate([jnp.zeros_like(xb[:1]), xb[:-1]], axis=0)
     next_xt = jnp.concatenate([xt[1:], jnp.zeros_like(xt[:1])], axis=0)
-    x = g - jnp.matmul(factors.w_spikes, prev_xb) - jnp.matmul(factors.v_spikes, next_xt)
+    hi = jax.lax.Precision.HIGHEST  # f32-accurate on TPU (DEFAULT is bf16 passes)
+    x = (g - jnp.matmul(factors.w_spikes, prev_xb, precision=hi)
+         - jnp.matmul(factors.v_spikes, next_xt, precision=hi))
     return x.reshape(d * factors.m, k)[: factors.n]
 
 

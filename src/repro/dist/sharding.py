@@ -23,8 +23,8 @@ those names to physical mesh axes:
     analysis artifacts.
   * ``split_axes`` / ``prepend_axis`` — pytree helpers for the
     ``(array, axes)`` leaf convention used by every ``init_*``.
-  * ``shard_map`` — thin version-compat wrapper over JAX's shard_map (the
-    ``check_vma``/``check_rep`` rename and module move).
+  * ``shard_map`` — keyword wrapper over ``jax.shard_map`` that every
+    repo call site goes through.
 """
 from __future__ import annotations
 
@@ -307,29 +307,11 @@ def prepend_axis(axes_tree, name: str):
 
 
 # ---------------------------------------------------------------------------
-# shard_map version compat
+# shard_map
 # ---------------------------------------------------------------------------
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """JAX-version-portable ``shard_map``.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=)``; older releases
-    have ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  All
-    repo call sites go through here so the skew lives in one place.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma,
-            )
-        except TypeError:  # transitional releases: jax.shard_map w/ check_rep
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma,
-            )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+    """``jax.shard_map`` with keyword-only specs; every repo call site goes
+    through here."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
     )

@@ -23,9 +23,10 @@ kernels, all single-dispatch (one ``pallas_call`` per factorization/solve):
                                   slab through a bounded VMEM buffer — ``n``
                                   is no longer capped by band-fits-VMEM.
 * :func:`banded_solve_kernelized` — blocked forward/backward substitution on
-                                  the packed band factors (HBM-resident,
-                                  one ``(C, C+2bw)`` coupling strip DMA'd
-                                  per block), mirroring ``trsm.py``'s
+                                  the packed band factors (factors and RHS
+                                  HBM-resident; one ``(C, C+2bw)`` coupling
+                                  strip and one RHS window DMA'd per
+                                  block), mirroring ``trsm.py``'s
                                   strip-recurrence + rank-``C2`` retirement;
                                   RHS column tiles across the grid.
 * :func:`batched_banded_lu_vmem` / :func:`batched_banded_solve_vmem` — the
@@ -37,7 +38,10 @@ kernels, all single-dispatch (one ``pallas_call`` per factorization/solve):
 All blocked kernels trace the exact window-helper jaxprs of the pure-jnp
 mirrors in :mod:`repro.core.banded`, so kernel and mirror produce
 **bitwise-identical** packed band factors.  The legacy scalar kernel
-(:func:`banded_lu_kernelized`) is kept as the measured baseline.
+(:func:`banded_lu_kernelized`) is kept as the measured baseline.  On TPU
+the blocked, tiled and solve kernels lower to Mosaic (skewed-band rows and
+narrow RHS are zero-padded to whole 128-lane tiles when compiled); the
+scalar, inverted-solve and batched kernels run in interpret mode only.
 """
 from __future__ import annotations
 
@@ -58,7 +62,10 @@ from repro.core.banded import (
     unit_lower_window_solve,
     upper_window_solve,
 )
+from repro.core.blocked import dot_f32
 from repro.core.factorization import equalized_rhs_tile, inverted_band_sweeps
+
+from . import aligned, interpret_mode, lane_pad, require_interpret, vmem_limit
 
 __all__ = [
     "banded_lu_kernelized",
@@ -102,9 +109,12 @@ def _banded_kernel(ap_ref, out_ref, *, n: int, bw: int):
 def banded_lu_kernelized(arow: jax.Array, *, bw: int, interpret: bool | None = None) -> jax.Array:
     """Row-aligned band (n, 2bw+1) → packed band LU, one scalar-sequential
     Pallas kernel (``n−1`` rank-1 ``fori_loop`` steps — the pre-blocked
-    baseline; see :func:`banded_lu_blocked` for the fast path)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    baseline; see :func:`banded_lu_blocked` for the fast path).
+    Interpret mode only: the body slices its VMEM value with traced
+    ``dynamic_slice``, which Mosaic refuses."""
+    interpret = require_interpret(
+        "banded.banded_lu_kernelized", "value-level dynamic_slice in the kernel body", interpret
+    )
     n = arow.shape[0]
     ap = jnp.concatenate([arow, jnp.zeros((bw, arow.shape[1]), arow.dtype)], axis=0)
     out = pl.pallas_call(
@@ -119,10 +129,13 @@ def banded_lu_kernelized(arow: jax.Array, *, bw: int, interpret: bool | None = N
 # blocked band LU — VMEM-resident megakernel
 # ---------------------------------------------------------------------------
 def _banded_blocked_kernel(g_ref, out_ref, *, num_steps: int, block: int, bw: int):
-    step = functools.partial(band_block_step, block=block, bw=bw)
-    out_ref[...] = jax.lax.fori_loop(
-        0, num_steps, lambda s, g: step(g, s * block), g_ref[...]
-    )
+    out_ref[...] = g_ref[...]  # output VMEM blocks start uninitialized on TPU
+
+    def step(i, carry):
+        band_block_step(out_ref, aligned(i * block, block), block=block, bw=bw)
+        return carry
+
+    jax.lax.fori_loop(0, num_steps, step, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "block", "interpret"))
@@ -136,17 +149,20 @@ def banded_lu_blocked(
     ``fori_loop`` steps assembles its dense ``(C+bw, C+bw)`` window from two
     static slices and retires ``C`` pivot rows.  Bitwise-identical to the
     :func:`repro.core.banded.banded_lu_blocked` mirror."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
     n = arow.shape[0]
     c = band_block_size(n, bw, block)
-    g, s = skew_pad(arow, bw, c)
+    g0, s = skew_pad(arow, bw, c)
+    g = lane_pad(g0)
     out = pl.pallas_call(
         functools.partial(_banded_blocked_kernel, num_steps=s, block=c, bw=bw),
         out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(2 * g.size * g.dtype.itemsize)
+        ),
         interpret=interpret,
     )(g)
-    return skewed_to_band(out, bw, c)[:n]
+    return skewed_to_band(out[:, : g0.shape[1]], bw, c)[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -163,7 +179,7 @@ def _banded_tiled_kernel(g_any, o_any, slab_buf, sem, *, block: int, bw: int):
     load = pltpu.make_async_copy(hbm, slab_buf, sem)
     load.start()
     load.wait()
-    slab_buf[...] = band_block_step(slab_buf[...], 0, block=c, bw=bw)
+    band_block_step(slab_buf, 0, block=c, bw=bw)
     store = pltpu.make_async_copy(slab_buf, hbm, sem)
     store.start()
     store.wait()
@@ -180,16 +196,16 @@ def banded_lu_tiled(
     factorization scales past the band-fits-VMEM wall of
     :func:`banded_lu_blocked`.  Bitwise-identical to the blocked mirror
     (same window helpers)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
     n = arow.shape[0]
     c = band_block_size(n, bw, block)
-    g, s = skew_pad(arow, bw, c)
+    g0, s = skew_pad(arow, bw, c)
+    g = lane_pad(g0)
     out = pl.pallas_call(
         functools.partial(_banded_tiled_kernel, block=c, bw=bw),
         grid=(s,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
         scratch_shapes=[
             pltpu.VMEM((c + bw, g.shape[1]), g.dtype),
@@ -198,7 +214,7 @@ def banded_lu_tiled(
         input_output_aliases={0: 0},
         interpret=interpret,
     )(g)
-    return skewed_to_band(out, bw, c)[:n]
+    return skewed_to_band(out[:, : g0.shape[1]], bw, c)[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -206,20 +222,19 @@ def banded_lu_tiled(
 # ---------------------------------------------------------------------------
 def _banded_solve_sweeps(read_strip, xp, *, num_steps: int, block: int, bw: int):
     """Blocked forward then backward band substitution on a carried RHS
-    value.  ``read_strip(k)`` yields the skewed factors' dense coupling
-    strip ``F`` ``(C, C+2bw)`` of the block at row ``k`` (a DMA'd copy or a
-    value slice — both exact, so the bitwise mirror contract holds).  The
-    carried RHS has ``bw`` zero margin rows at both ends so every block
-    reads its above/below coupling window without branching."""
+    value (the batched grid kernel's VMEM-resident form).  ``read_strip(k)``
+    yields the skewed factors' dense coupling strip ``F`` ``(C, C+2bw)`` of
+    the block at row ``k``.  The carried RHS has ``bw`` zero margin rows at
+    both ends so every block reads its above/below coupling window without
+    branching."""
     c = block
     rt = xp.shape[1]
 
     def fwd(i, xp):
         k = i * c
         f = read_strip(k)
-        yblk = jax.lax.dynamic_slice(xp, (bw + k, 0), (c, rt)) - jnp.dot(
-            f[:, :bw], jax.lax.dynamic_slice(xp, (k, 0), (bw, rt)),
-            preferred_element_type=jnp.float32,
+        yblk = jax.lax.dynamic_slice(xp, (bw + k, 0), (c, rt)) - dot_f32(
+            f[:, :bw], jax.lax.dynamic_slice(xp, (k, 0), (bw, rt))
         ).astype(xp.dtype)
         yblk = unit_lower_window_solve(f[:, bw : bw + c], yblk, bw)
         return jax.lax.dynamic_update_slice(xp, yblk, (bw + k, 0))
@@ -229,9 +244,8 @@ def _banded_solve_sweeps(read_strip, xp, *, num_steps: int, block: int, bw: int)
     def bwd(ii, xp):
         k = (num_steps - 1 - ii) * c
         f = read_strip(k)
-        xblk = jax.lax.dynamic_slice(xp, (bw + k, 0), (c, rt)) - jnp.dot(
-            f[:, bw + c :], jax.lax.dynamic_slice(xp, (bw + k + c, 0), (bw, rt)),
-            preferred_element_type=jnp.float32,
+        xblk = jax.lax.dynamic_slice(xp, (bw + k, 0), (c, rt)) - dot_f32(
+            f[:, bw + c :], jax.lax.dynamic_slice(xp, (bw + k + c, 0), (bw, rt))
         ).astype(xp.dtype)
         xblk = upper_window_solve(f[:, bw : bw + c], xblk, bw)
         return jax.lax.dynamic_update_slice(xp, xblk, (bw + k, 0))
@@ -239,21 +253,48 @@ def _banded_solve_sweeps(read_strip, xp, *, num_steps: int, block: int, bw: int)
     return jax.lax.fori_loop(0, num_steps, bwd, xp)
 
 
-def _banded_solve_kernel(g_any, b_ref, x_ref, fbuf, sem, *, num_steps: int, block: int, bw: int):
-    """One RHS-tile program.  The skewed factors stay in HBM (``ANY``
-    memspace); only one ``(C, C+2bw)`` coupling strip is DMA'd to VMEM
-    scratch at a time — per-program VMEM is ``(2bw+S·C+...)·rt + C·(C+2bw)``
-    floats, the band analogue of ``trsm.py:solve_tiled``'s footprint."""
+def _banded_solve_kernel(g_any, b_any, x_any, fbuf, wbuf, sem, *, num_steps: int, block: int, bw: int):
+    """One RHS-tile program.  The skewed factors and the padded RHS both
+    stay in HBM (``ANY`` memspace; ``x_any`` aliases ``b_any``): per block
+    one ``(C, C+2bw)`` coupling strip and one ``(C+2bw, rt)`` RHS window
+    are DMA'd to VMEM, so VMEM holds ``C·(C+2bw) + (C+2bw)·rt`` floats
+    whatever ``n`` — the band analogue of ``trsm.py:solve_tiled``.  Same
+    op sequence as :func:`repro.core.banded.banded_solve_blocked`."""
+    del b_any  # aliased to x_any
+    c = block
+    rt = wbuf.shape[1]  # a 128 multiple whenever there are several column tiles
+    cols = x_any.at[:, pl.ds(pl.multiple_of(pl.program_id(0) * rt, 128), rt)]
 
-    def read_strip(k):
-        dma = pltpu.make_async_copy(g_any.at[pl.ds(k, block), :], fbuf, sem)
+    def copy(src, dst):
+        dma = pltpu.make_async_copy(src, dst, sem)
         dma.start()
         dma.wait()
-        return fbuf[...]
 
-    x_ref[...] = _banded_solve_sweeps(
-        read_strip, b_ref[...], num_steps=num_steps, block=block, bw=bw
-    )
+    def fwd(i, carry):
+        k = i * c
+        copy(g_any.at[pl.ds(k, c), :], fbuf)  # strip F (C, C+2bw), maybe lane-padded
+        # rows [k, k+bw) hold the solved tail above, [bw+k, bw+k+c) the block
+        copy(cols.at[pl.ds(k, bw + c), :], wbuf.at[pl.ds(0, bw + c), :])
+        f = fbuf[...]
+        yblk = wbuf[bw : bw + c, :] - dot_f32(f[:, :bw], wbuf[0:bw, :]).astype(wbuf.dtype)
+        wbuf[bw : bw + c, :] = unit_lower_window_solve(f[:, bw : bw + c], yblk, bw)
+        copy(wbuf.at[pl.ds(bw, c), :], cols.at[pl.ds(bw + k, c), :])
+        return carry
+
+    jax.lax.fori_loop(0, num_steps, fwd, 0)
+
+    def bwd(ii, carry):
+        k = (num_steps - 1 - ii) * c
+        copy(g_any.at[pl.ds(k, c), :], fbuf)
+        # rows [bw+k, bw+k+c) hold the block, [bw+k+c, bw+k+c+bw) the head below
+        copy(cols.at[pl.ds(bw + k, c + bw), :], wbuf.at[pl.ds(0, c + bw), :])
+        f = fbuf[...]
+        xblk = wbuf[0:c, :] - dot_f32(f[:, bw + c : c + 2 * bw], wbuf[c : c + bw, :]).astype(wbuf.dtype)
+        wbuf[0:c, :] = upper_window_solve(f[:, bw : bw + c], xblk, bw)
+        copy(wbuf.at[pl.ds(0, c), :], cols.at[pl.ds(bw + k, c), :])
+        return carry
+
+    jax.lax.fori_loop(0, num_steps, bwd, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "block", "rhs_tile", "interpret"))
@@ -269,12 +310,11 @@ def banded_solve_kernelized(
     """Solve ``(LU) x = b`` on packed band factors in ONE ``pallas_call``:
     blocked forward/backward sweeps (strip recurrence + rank-``C2``
     retirement per block, the band analogue of ``trsm.py``), RHS column
-    tiles across the grid, factors HBM-resident and streamed strip-by-strip
-    so the solve is not capped by factors-fit-VMEM.  Bitwise-identical to
+    tiles across the grid, factors and RHS HBM-resident and streamed
+    block-by-block so the solve is not capped by VMEM.  Bitwise-identical to
     :func:`repro.core.banded.banded_solve_blocked`."""
     lu_band = getattr(lu_band, "packed", lu_band)  # accept artifacts
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
     n = lu_band.shape[0]
     squeeze = b.ndim == 1
     bm = b[:, None] if squeeze else b
@@ -282,24 +322,32 @@ def banded_solve_kernelized(
     c = band_block_size(n, bw, block)
     s = -(-n // c)
     np_rows = s * c
-    g = band_to_skewed(pad_band_identity(lu_band, bw, np_rows), bw, c)
-    rt = min(rhs_tile, m)
-    m_pad = -(-m // rt) * rt
+    g = lane_pad(band_to_skewed(pad_band_identity(lu_band, bw, np_rows), bw, c))
+    # compiled: whole lane tiles.  Interpret mode keeps the RHS at its own
+    # width: a wider RHS changes XLA:CPU's dot accumulation order, and the
+    # kernel≡mirror tests compare bitwise (the padded band above is inert).
+    mw = m if interpret else lane_pad(bm[:1]).shape[1]
+    rt = min(rhs_tile, mw)
+    if rt < mw:  # several column tiles: each must start on a 128-lane boundary
+        rt = max(128, rt // 128 * 128)
+    m_pad = -(-mw // rt) * rt
     p_rows = bw + np_rows + bw
     xp = jnp.zeros((p_rows, m_pad), bm.dtype).at[bw : bw + n, :m].set(bm)
     x = pl.pallas_call(
         functools.partial(_banded_solve_kernel, num_steps=s, block=c, bw=bw),
         grid=(m_pad // rt,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((p_rows, rt), lambda j: (0, j)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((p_rows, rt), lambda j: (0, j)),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((p_rows, m_pad), bm.dtype),
         scratch_shapes=[
             pltpu.VMEM((c, g.shape[1]), g.dtype),
+            pltpu.VMEM((c + 2 * bw, rt), bm.dtype),
             pltpu.SemaphoreType.DMA,
         ],
+        input_output_aliases={1: 0},
         interpret=interpret,
     )(g, xp)
     x = x[bw : bw + n, :m]
@@ -346,9 +394,12 @@ def banded_solve_inverted(
     Like ``banded_lu_blocked``, this is the VMEM-resident variant: the
     ``(S, C, C)`` inverse stacks live in VMEM for the whole program (the
     artifact payload the registry's VMEM estimate accounts for); an
-    HBM-streaming phase-split variant is the escape hatch past that wall."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    HBM-streaming phase-split variant is the escape hatch past that wall.
+    Interpret mode only: Mosaic does not lower the in-kernel
+    ``lax.associative_scan`` of the tail recurrence."""
+    interpret = require_interpret(
+        "banded.banded_solve_inverted", "in-kernel lax.associative_scan", interpret
+    )
     s, c = linv.shape[0], linv.shape[1]
     squeeze = b.ndim == 1
     bm = b[:, None] if squeeze else b
@@ -385,8 +436,13 @@ def banded_solve_inverted(
 # batched band grid path (optimizer: many small independent systems)
 # ---------------------------------------------------------------------------
 def _batched_banded_lu_kernel(g_ref, o_ref, *, num_steps: int, block: int, bw: int):
-    step = functools.partial(band_block_step, block=block, bw=bw)
-    o_ref[0] = jax.lax.fori_loop(0, num_steps, lambda s, g: step(g, s * block), g_ref[0])
+    o_ref[...] = g_ref[...]
+
+    def step(i, carry):
+        band_block_step(o_ref.at[0], i * block, block=block, bw=bw)
+        return carry
+
+    jax.lax.fori_loop(0, num_steps, step, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "block", "interpret"))
@@ -396,9 +452,11 @@ def batched_banded_lu_vmem(
     """(B, n, 2bw+1) → packed band LU per system; one grid program per
     system, each running the blocked window steps on its VMEM-resident band
     (equal work per program by construction — every system is one identical
-    factorization)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    factorization).  Interpret mode only: the row offsets into the
+    per-system block are not provably sublane-aligned for every band."""
+    interpret = require_interpret(
+        "banded.batched_banded_lu_vmem", "unaligned dynamic row slices", interpret
+    )
     bsz, n, w = arow.shape
     c = band_block_size(n, bw, block)
     g = jax.vmap(lambda ap: skew_pad(ap, bw, c)[0])(arow)
@@ -432,10 +490,12 @@ def batched_banded_solve_vmem(
     interpret: bool | None = None,
 ) -> jax.Array:
     """lu_band: (B, n, 2bw+1) packed; b: (B, n) or (B, n, m) → x, same shape
-    as ``b``; one grid program per system."""
+    as ``b``; one grid program per system.  Interpret mode only: the
+    sweeps slice the carried RHS value with traced ``dynamic_slice``."""
     lu_band = getattr(lu_band, "packed", lu_band)  # accept artifacts
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = require_interpret(
+        "banded.batched_banded_solve_vmem", "value-level dynamic_slice in the sweeps", interpret
+    )
     bsz, n, w = lu_band.shape
     squeeze = b.ndim == 2
     bm = b[..., None] if squeeze else b

@@ -14,7 +14,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import require_interpret
 from .ebv_lu import _lu_body
+
+# Both kernels run the rank-1 bodies on a VMEM value with traced
+# dynamic_slice, which Mosaic refuses: interpret mode only.
+_REFUSAL = "value-level dynamic_slice in the kernel body"
 
 __all__ = ["batched_lu_vmem", "batched_lu_solve_vmem"]
 
@@ -27,8 +32,7 @@ def _batched_lu_kernel(a_ref, o_ref, *, steps: int):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def batched_lu_vmem(a: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """(B, n, n) → packed LU per matrix; grid over the batch."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = require_interpret("batched_lu.batched_lu_vmem", _REFUSAL, interpret)
     bsz, n, _ = a.shape
     return pl.pallas_call(
         functools.partial(_batched_lu_kernel, steps=n - 1),
@@ -66,8 +70,7 @@ def _batched_solve_kernel(lu_ref, b_ref, x_ref, *, n: int):
 def batched_lu_solve_vmem(lu: jax.Array, b: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """lu: (B, n, n) packed; b: (B, n, m) → x: (B, n, m)."""
     lu = getattr(lu, "packed", lu)  # accept Factorization artifacts
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = require_interpret("batched_lu.batched_lu_solve_vmem", _REFUSAL, interpret)
     bsz, n, _ = lu.shape
     m = b.shape[-1]
     return pl.pallas_call(

@@ -29,9 +29,11 @@ Kernels, mirroring DESIGN.md §2's GPU→TPU adaptation:
 * :func:`update`        — standalone rank-k update GEMM (2-D tile grid) for
                           trailing blocks too tall for the fused kernel.
 
-All kernels run under ``interpret=True`` on CPU (how we validate here) and
-lower to Mosaic on real TPUs.  MXU alignment: tile sizes default to multiples
-of 128; iotas are 2-D (TPU requirement).
+All kernels run under ``interpret=True`` on the CPU platform.  Only
+:func:`lu_fused` lowers to Mosaic: the other four run the rank-1 body on a
+VMEM *value* with traced ``dynamic_slice``, which Mosaic refuses, so on
+TPU they raise (:func:`repro.kernels.require_interpret`) and the registry
+never selects them there.
 """
 from __future__ import annotations
 
@@ -43,6 +45,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blocked import (
+    FUSED_VMEM_MAX_N,
+    dot_f32,
     factor_diag_strip,
     fused_block_size,
     fused_lu_steps,
@@ -52,15 +56,16 @@ from repro.core.blocked import (
     sub_block_width,
 )
 
+from . import aligned, interpret_mode, require_interpret, vmem_limit
+
 __all__ = ["lu_fused", "lu_vmem", "panel", "fused_step", "update"]
 
-# Padded orders at or below this run the fused LU as a VMEM-resident value
-# kernel (no HBM scratch streaming).  The HBM megakernel's interpret-mode
-# DMA emulation and per-strip scratch-ref copies made it *slower* than its
-# own pure-jnp mirror at n=256 (3460 vs 3166 µs, BENCH_kernels.json seed);
-# on a VMEM value the kernel traces exactly the mirror's ops.  2·N²·4 bytes
-# of VMEM at N=512 is 2 MB — comfortable on real TPUs too.
-_FUSED_VMEM_MAX_N = 512
+# Padded orders at or below this run the fused LU on one VMEM-resident
+# block (no HBM scratch streaming), tracing exactly the mirror's ref ops.
+# 2·N²·4 bytes of VMEM at N=512 is 2 MB.
+_FUSED_VMEM_MAX_N = FUSED_VMEM_MAX_N
+
+_DYNAMIC_SLICE = "value-level dynamic_slice in the kernel body"
 
 
 def _rows_cols(m: int, n: int):
@@ -96,11 +101,10 @@ def _lu_vmem_kernel(a_ref, o_ref, *, steps: int):
 def lu_vmem(a: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Whole-matrix VMEM-resident EbV LU (paper-faithful kernel).
 
-    Fits matrices up to ~4096² fp32 in v5e VMEM; larger inputs should use the
-    blocked driver in :mod:`repro.kernels.ops`.
+    Interpret mode only (see module docstring); larger inputs and TPU use
+    :func:`lu_fused`.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = require_interpret("ebv_lu.lu_vmem", _DYNAMIC_SLICE, interpret)
     n = a.shape[-1]
     return pl.pallas_call(
         functools.partial(_lu_vmem_kernel, steps=n - 1),
@@ -118,8 +122,7 @@ def _panel_kernel(p_ref, o_ref, *, steps: int):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def panel(p: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Tall (m, b) panel factorization, pivots in the top b rows."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = require_interpret("ebv_lu.panel", _DYNAMIC_SLICE, interpret)
     b = p.shape[-1]
     return pl.pallas_call(
         functools.partial(_panel_kernel, steps=b),
@@ -162,8 +165,7 @@ def fused_step(
     """Fused bi-vector step.  ``pan``: (m, b) factored packed panel;
     ``a_top``: (b, W) A12 rows; ``a_trail``: (m-b, W) A22.
     Returns (U12, updated A22)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = require_interpret("ebv_lu.fused_step", _DYNAMIC_SLICE, interpret)
     m, b = pan.shape
     w = a_top.shape[1]
     ct = min(col_tile, w)
@@ -208,8 +210,11 @@ def _fused_lu_kernel(a_any, o_any, panel_buf, tile1_buf, tile2_buf, sems, *, num
     s = pl.program_id(0)
     p = pl.program_id(1)
     S, B = num_steps, block
-    N = S * B
     C2 = sub_block_width(B)  # shared with the pure-jnp mirror (bitwise twin)
+    base = aligned(s * B, B)
+
+    def rows(off, size):
+        return pl.ds(aligned(off, B), size)
 
     def copy_live_rows(buf, sem, src_cols, to_hbm):
         """DMA a column slab one (B, B) row block at a time, rows ``s*B``
@@ -218,7 +223,7 @@ def _fused_lu_kernel(a_any, o_any, panel_buf, tile1_buf, tile2_buf, sems, *, num
 
         def blk_copy(r, _):
             hbm = o_any.at[pl.ds(r * B, B), pl.ds(src_cols, B)]
-            vmem = buf.at[pl.ds(r * B, B), :]
+            vmem = buf.at[rows(r * B, B), :]
             dma = pltpu.make_async_copy(*((vmem, hbm) if to_hbm else (hbm, vmem)), sem)
             dma.start()
             dma.wait()
@@ -229,39 +234,37 @@ def _fused_lu_kernel(a_any, o_any, panel_buf, tile1_buf, tile2_buf, sems, *, num
     @pl.when(p == 0)
     def _factor_panel():
         copy_live_rows(panel_buf, sems.at[0], s * B, to_hbm=False)
-        base = s * B
 
         # All sequential recurrences run on small array carries through the
         # shared core.blocked strip helpers (the pure-jnp mirror traces the
         # same jaxprs — bitwise equality by construction) and write scratch
-        # back once per strip: interpret-mode ref writes copy the whole
-        # scratch buffer, and on TPU fewer, larger stores pipeline better.
+        # back once per strip.
         for j in range(0, B, C2):
             # (1) bi-vectorized factorization of the diagonal-block strip
-            diag = factor_diag_strip(panel_buf[pl.ds(base, B), pl.ds(j, C2)], j)
-            panel_buf[pl.ds(base, B), pl.ds(j, C2)] = diag
+            diag = factor_diag_strip(panel_buf[rows(base, B), pl.ds(j, C2)], j)
+            panel_buf[rows(base, B), pl.ds(j, C2)] = diag
 
             # (2) unit-lower trsm: U rows of the strip vs the remaining cols
             w = B - j - C2
             if w:
-                u = strip_trsm(diag[j : j + C2, :], panel_buf[pl.ds(base + j, C2), pl.ds(j + C2, w)])
-                panel_buf[pl.ds(base + j, C2), pl.ds(j + C2, w)] = u
+                u = strip_trsm(diag[j : j + C2, :], panel_buf[rows(base + j, C2), pl.ds(j + C2, w)])
+                panel_buf[rows(base + j, C2), pl.ds(j + C2, w)] = u
                 lpart = diag[j + C2 :, :]
-                blk = panel_buf[pl.ds(base + j + C2, w), pl.ds(j + C2, w)]
-                panel_buf[pl.ds(base + j + C2, w), pl.ds(j + C2, w)] = (
-                    blk - jnp.dot(lpart, u, preferred_element_type=jnp.float32)
+                blk = panel_buf[rows(base + j + C2, w), pl.ds(j + C2, w)]
+                panel_buf[rows(base + j + C2, w), pl.ds(j + C2, w)] = (
+                    blk - dot_f32(lpart, u)
                 ).astype(blk.dtype)
 
             # (3) row blocks below: multipliers via right-solve against the
             # factored strip, then the rank-C2 GEMM retirement
             def rblk(r, _):
                 off = r * B
-                strip = solve_below_strip(diag, panel_buf[pl.ds(off, B), pl.ds(j, C2)], j)
-                panel_buf[pl.ds(off, B), pl.ds(j, C2)] = strip
+                strip = solve_below_strip(diag, panel_buf[rows(off, B), pl.ds(j, C2)], j)
+                panel_buf[rows(off, B), pl.ds(j, C2)] = strip
                 if w:
-                    blkr = panel_buf[pl.ds(off, B), pl.ds(j + C2, w)]
-                    panel_buf[pl.ds(off, B), pl.ds(j + C2, w)] = (
-                        blkr - jnp.dot(strip, u, preferred_element_type=jnp.float32)
+                    blkr = panel_buf[rows(off, B), pl.ds(j + C2, w)]
+                    panel_buf[rows(off, B), pl.ds(j + C2, w)] = (
+                        blkr - dot_f32(strip, u)
                     ).astype(blkr.dtype)
                 return 0
 
@@ -294,32 +297,25 @@ def _fused_lu_kernel(a_any, o_any, panel_buf, tile1_buf, tile2_buf, sems, *, num
 
     def process(tbuf, sem, t):
         tile_load(tbuf, sem, t).wait()
-        base = s * B
 
         # Unit-lower trsm of the U12 tile, two-level: per C2-strip a short
-        # sequential axpy solve, then one rank-C2 GEMM retires the strip —
-        # all on a (B, B) array carry, written back to scratch once.
-        y = tbuf[pl.ds(base, B), :]
+        # sequential axpy solve, then one rank-C2 GEMM retires the strip.
         for j in range(0, B, C2):
-            ldiag = panel_buf[pl.ds(base + j, C2), pl.ds(j, C2)]
-            strip = strip_trsm(ldiag, y[j : j + C2, :])
-            y = jax.lax.dynamic_update_slice(y, strip, (j, 0))
+            ldiag = panel_buf[rows(base + j, C2), pl.ds(j, C2)]
+            strip = strip_trsm(ldiag, tbuf[rows(base + j, C2), :])
+            tbuf[rows(base + j, C2), :] = strip
             w = B - j - C2
             if w:
-                lpart = panel_buf[pl.ds(base + j + C2, w), pl.ds(j, C2)]
-                tail = (
-                    y[j + C2 :, :] - jnp.dot(lpart, strip, preferred_element_type=jnp.float32)
-                ).astype(y.dtype)
-                y = jax.lax.dynamic_update_slice(y, tail, (j + C2, 0))
-        tbuf[pl.ds(base, B), :] = y  # U12 tile
+                lpart = panel_buf[rows(base + j + C2, w), pl.ds(j, C2)]
+                tail = tbuf[rows(base + j + C2, w), :]
+                tbuf[rows(base + j + C2, w), :] = (tail - dot_f32(lpart, strip)).astype(tail.dtype)
+        y = tbuf[rows(base, B), :]  # U12 tile
 
         def row_body(r, _):
             off = r * B
-            blk = tbuf[pl.ds(off, B), :]
-            lblk = panel_buf[pl.ds(off, B), :]  # L21 row block of this step
-            tbuf[pl.ds(off, B), :] = blk - jnp.dot(
-                lblk, y, preferred_element_type=jnp.float32
-            ).astype(blk.dtype)
+            blk = tbuf[rows(off, B), :]
+            lblk = panel_buf[rows(off, B), :]  # L21 row block of this step
+            tbuf[rows(off, B), :] = blk - dot_f32(lblk, y).astype(blk.dtype)
             return 0
 
         jax.lax.fori_loop(s + 1, S, row_body, 0)
@@ -338,11 +334,12 @@ def _fused_lu_kernel(a_any, o_any, panel_buf, tile1_buf, tile2_buf, sems, *, num
 
 
 def _fused_vmem_lu_kernel(a_ref, o_ref, *, num_steps: int, block: int):
-    """Small-n fused LU: the padded matrix is one VMEM block and the kernel
-    runs the mirror's exact value-level step sequence — no DMA, no scratch
-    refs, still one ``pallas_call`` (and still bitwise-equal to the mirror
-    by construction)."""
-    o_ref[...] = fused_lu_steps(a_ref[...], block=block, num_steps=num_steps)
+    """Small-n fused LU: the padded matrix is one VMEM block, factored in
+    place by the mirror's exact ref-level step sequence — no DMA, still one
+    ``pallas_call`` (and still bitwise-equal to the mirror by
+    construction)."""
+    o_ref[...] = a_ref[...]  # output VMEM blocks start uninitialized on TPU
+    fused_lu_steps(o_ref, block=block, num_steps=num_steps)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -361,8 +358,7 @@ def lu_fused(a: jax.Array, *, block: int = 256, interpret: bool | None = None) -
     run the same step sequence on a VMEM-resident value — the small-n fast
     path (see ``_fused_vmem_lu_kernel``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
     n = a.shape[-1]
     if a.dtype not in (jnp.float32, jnp.bfloat16):
         raise TypeError(f"lu_fused supports float32/bfloat16 only, got {a.dtype}")
@@ -382,8 +378,8 @@ def lu_fused(a: jax.Array, *, block: int = 256, interpret: bool | None = None) -
     out = pl.pallas_call(
         functools.partial(_fused_lu_kernel, num_steps=S, block=B),
         grid=(S, num_programs),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((N, N), a.dtype),
         scratch_shapes=[
             pltpu.VMEM((N, B), a.dtype),
@@ -392,15 +388,16 @@ def lu_fused(a: jax.Array, *, block: int = 256, interpret: bool | None = None) -
             pltpu.SemaphoreType.DMA((3,)),
         ],
         input_output_aliases={0: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(3 * N * B * a.dtype.itemsize)
+        ),
         interpret=interpret,
     )(a)
     return out[:n, :n] if N != n else out
 
 
 def _update_kernel(l_ref, u_ref, c_ref, o_ref):
-    o_ref[...] = c_ref[...] - jnp.dot(
-        l_ref[...], u_ref[...], preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+    o_ref[...] = c_ref[...] - dot_f32(l_ref[...], u_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("row_tile", "col_tile", "interpret"))
@@ -415,8 +412,7 @@ def update(
 ) -> jax.Array:
     """Rank-k trailing update ``A22 − L21 @ U12`` on a 2-D tile grid (for
     trailing blocks too tall for :func:`fused_step`)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
     m, b = l21.shape
     w = u12.shape[1]
     rt, ct = min(row_tile, m), min(col_tile, w)

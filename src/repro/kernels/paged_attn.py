@@ -27,60 +27,76 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.models.common import MASK_VALUE
+
+from . import interpret_mode
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_ref"]
 
 
-def _row_attention(q_row, ks, vs, length):
-    """Single-row decode attention: the exact op sequence of the single-chunk
-    branch of :func:`repro.models.common.attention` (b=1, sq=1), so the paged
-    path stays bitwise-identical to the dense engine's per-row attention.
-
-    q_row: (H, Dh); ks/vs: (Sc, KV, Dh); length: scalar int32 (live tokens).
-    Returns (H * Dh,) in q_row.dtype.
-    """
-    h, dh = q_row.shape
-    sc, kvh, _ = ks.shape
-    rep = h // kvh
-    qg = q_row.reshape(1, 1, kvh, rep, dh).transpose(0, 2, 3, 1, 4)
-    scale = dh**-0.5
-    s = jnp.einsum(
-        "bgrqd,bkgd->bgrqk", qg, ks[None], preferred_element_type=jnp.float32
+def _group_attention(q_g, k_g, v_g, length):
+    """Decode attention of one KV head group: ``q_g`` (rep, Dh) queries
+    against ``k_g``/``v_g`` (Sc, Dh).  The op sequence of the single-chunk
+    branch of :func:`repro.models.common.attention` restricted to one
+    group (the dense path's batched einsum runs this same 2-D contraction
+    per (batch, group)), written with 2-D dots and a 2-D iota so Mosaic
+    lowers it.  Returns (rep, Dh) in ``q_g.dtype``."""
+    sc, dh = k_g.shape
+    s = jax.lax.dot_general(
+        q_g, k_g, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    s = s * scale
+    s = s * dh**-0.5
     # contiguous paged rows: kv position j is valid iff j < length, which is
     # exactly the dense path's (pos >= 0) & (pos <= cur) mask
-    mask = jnp.arange(sc, dtype=jnp.int32) < length
-    s = jnp.where(mask[None, None, None, None, :], s, MASK_VALUE)
+    mask = jax.lax.broadcasted_iota(jnp.int32, (1, sc), 1) < length
+    s = jnp.where(mask, s, MASK_VALUE)
     m = jnp.maximum(s.max(-1), -1e25)
-    p = jnp.exp(s - m[..., None])
-    out = jnp.einsum("bgrqk,bkgd->bgrqd", p.astype(vs.dtype), vs[None])
-    out = out / jnp.maximum(p.sum(-1), 1e-30)[..., None].astype(out.dtype)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(1, 1, h * dh)
-    return out[0, 0].astype(q_row.dtype)
+    p = jnp.exp(s - m[:, None])
+    # f32 accumulation, rounded to the value dtype (what the dense path's
+    # einsum does; Mosaic needs the 32-bit accumulator spelled out)
+    out = jnp.dot(p.astype(v_g.dtype), v_g, preferred_element_type=jnp.float32).astype(v_g.dtype)
+    out = out / jnp.maximum(p.sum(-1), 1e-30)[:, None].astype(out.dtype)
+    return out.astype(q_g.dtype)
+
+
+def _row_attention(q_row, read_kv, kvh: int, length):
+    """One batch row: every KV head group against its gathered pages.
+    ``read_kv(g)`` yields group ``g``'s (Sc, Dh) keys and values."""
+    rep = q_row.shape[0] // kvh
+    outs = []
+    for g in range(kvh):
+        k_g, v_g = read_kv(g)
+        outs.append(_group_attention(q_row[g * rep : (g + 1) * rep], k_g, v_g, length))
+    return jnp.concatenate(outs, axis=0)  # (H, Dh)
 
 
 def _paged_attn_kernel(q_ref, pt_ref, len_ref, kp_ref, vp_ref, o_ref, *, num_row_pages: int):
-    """One batch row: gather the row's pages, then single-chunk attention.
+    """One batch row: gather the row's pages per KV head, then single-chunk
+    attention.
 
-    q_ref (1, H, Dh); pt_ref (1, NP) int32 page table row (−1 = unallocated);
-    len_ref (1, 1) int32; kp/vp_ref (P, page, KV, Dh) full pool; o (1, H·Dh).
+    q_ref (1, H, Dh); pt_ref (B, NP) int32 page table in SMEM (−1 =
+    unallocated); len_ref (B,) int32 in SMEM; kp/vp_ref (P, KV, page, Dh)
+    full pool, head-major so one head of one page is a tile-aligned
+    (page, Dh) slab; o_ref (1, H, Dh).
     """
-    full = (slice(None), slice(None), slice(None))
-    ks_parts, vs_parts = [], []
-    for j in range(num_row_pages):
-        pid = pt_ref[0, j]
-        safe = jnp.maximum(pid, 0)
-        pk = pl.load(kp_ref, (pl.dslice(safe, 1),) + full)[0]
-        pv = pl.load(vp_ref, (pl.dslice(safe, 1),) + full)[0]
-        hole = pid < 0
-        ks_parts.append(jnp.where(hole, jnp.zeros_like(pk), pk))
-        vs_parts.append(jnp.where(hole, jnp.zeros_like(pv), pv))
-    ks = jnp.concatenate(ks_parts, axis=0)  # (NP * page, KV, Dh)
-    vs = jnp.concatenate(vs_parts, axis=0)
-    o_ref[0] = _row_attention(q_ref[0], ks, vs, len_ref[0, 0])
+    i = pl.program_id(0)
+    kvh = kp_ref.shape[1]
+
+    def read_kv(g):
+        ks_parts, vs_parts = [], []
+        for j in range(num_row_pages):
+            pid = pt_ref[i, j]
+            safe = jnp.maximum(pid, 0)
+            pk = kp_ref[safe, g]
+            pv = vp_ref[safe, g]
+            hole = pid < 0
+            ks_parts.append(jnp.where(hole, jnp.zeros_like(pk), pk))
+            vs_parts.append(jnp.where(hole, jnp.zeros_like(pv), pv))
+        return jnp.concatenate(ks_parts, axis=0), jnp.concatenate(vs_parts, axis=0)
+
+    o_ref[0] = _row_attention(q_ref[0], read_kv, kvh, len_ref[i])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -95,26 +111,30 @@ def paged_decode_attention(
     lengths: (B,) int32 live tokens per row (the current position + 1).
     Returns (B, H * Dh) attention outputs in q.dtype.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
     b, h, dh = q.shape
     p, page, kvh, _ = k_pages.shape
     np_ = page_table.shape[1]
-    lens2 = jnp.asarray(lengths, jnp.int32).reshape(b, 1)
-    return pl.pallas_call(
+    # head-major pool: one (page, Dh) slab per (page id, head) — the layout
+    # Mosaic can index with a traced page id
+    kt = k_pages.transpose(0, 2, 1, 3)
+    vt = v_pages.transpose(0, 2, 1, 3)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, num_row_pages=np_),
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, h, dh), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((p, page, kvh, dh), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((p, page, kvh, dh), lambda i: (0, 0, 0, 0)),
+            smem,
+            smem,
+            pl.BlockSpec((p, kvh, page, dh), lambda i: (0, 0, 0, 0)),
+            pl.BlockSpec((p, kvh, page, dh), lambda i: (0, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h * dh), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h * dh), q.dtype),
+        out_specs=pl.BlockSpec((1, h, dh), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
         interpret=interpret,
-    )(q, page_table, lens2, k_pages, v_pages)
+    )(q, page_table.astype(jnp.int32), jnp.asarray(lengths, jnp.int32).reshape(b), kt, vt)
+    return out.reshape(b, h * dh)
 
 
 def paged_decode_attention_ref(q, k_pages, v_pages, page_table, lengths):
@@ -125,4 +145,9 @@ def paged_decode_attention_ref(q, k_pages, v_pages, page_table, lengths):
     hole = (page_table < 0)[..., None, None, None]
     ks = jnp.where(hole, 0, k_pages[safe]).reshape(b, np_ * page, kvh, dh)
     vs = jnp.where(hole, 0, v_pages[safe]).reshape(b, np_ * page, kvh, dh)
-    return jax.vmap(_row_attention)(q, ks, vs, jnp.asarray(lengths, jnp.int32))
+
+    def row(q_row, k_row, v_row, length):
+        out = _row_attention(q_row, lambda g: (k_row[:, g], v_row[:, g]), kvh, length)
+        return out.reshape(-1)
+
+    return jax.vmap(row)(q, ks, vs, jnp.asarray(lengths, jnp.int32))

@@ -3,9 +3,10 @@
 The multi-device realization of :mod:`repro.core.spike`: the stacked
 per-partition operands (leading ``devices`` axis) are laid over a mesh axis
 with ``shard_map``, each device runs the existing single-dispatch Pallas
-megakernels locally — :func:`repro.kernels.banded.banded_lu_blocked` for the
-block factor, :func:`repro.kernels.banded.banded_solve_kernelized` for the
-spike/``g`` solves — and everything *around* the local work (partitioning,
+megakernels locally — :func:`repro.kernels.banded.banded_lu_blocked` (or
+``banded_lu_tiled`` for bands past the VMEM cap) for the block factor,
+:func:`repro.kernels.banded.banded_solve_kernelized` for the spike/``g``
+solves — and everything *around* the local work (partitioning,
 coupling extraction, reduced-system assembly and tip solve, recovery) is the
 exact shared code from :mod:`repro.core.spike`.  Kernel-vs-mirror bitwise
 equality therefore reduces to the established per-partition kernel/mirror
@@ -23,16 +24,29 @@ import functools
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core import banded as core_banded
 from repro.core import spike as core_spike
 from repro.dist.sharding import shard_map
 
 from . import banded as kbanded
+from . import interpret_mode
 
 __all__ = [
     "spike_lu_sharded",
     "spike_solve_sharded",
     "spike_linear_solve_sharded",
 ]
+
+
+def _local_factor(p, *, bw: int, block: int | None, interpret: bool | None):
+    """Per-partition band factor: the VMEM-resident blocked kernel where the
+    registry's rule lets it take the band, the HBM-streaming tiled kernel
+    otherwise (both bitwise twins of the same mirror)."""
+    compiled = not interpret_mode(interpret)
+    blocked = core_banded.blocked_kernel_takes(
+        p.shape[0], bw, block, p.dtype.itemsize, compiled=compiled)
+    kernel = kbanded.banded_lu_blocked if blocked else kbanded.banded_lu_tiled
+    return kernel(p, bw=bw, block=block, interpret=interpret)
 
 
 # The jitted shard_map entries are cached per (mesh, axis, kernel params):
@@ -46,7 +60,7 @@ def _factor_entry(mesh, axis: str, bw: int, block: int | None,
     def local_fn(p, r):
         p = p[0] if p.ndim == 3 else p
         r = r[0] if r.ndim == 3 else r
-        lu = kbanded.banded_lu_blocked(p, bw=bw, block=block, interpret=interpret)
+        lu = _local_factor(p, bw=bw, block=block, interpret=interpret)
         wv = kbanded.banded_solve_kernelized(
             lu, r, bw=bw, block=block, interpret=interpret
         )
@@ -105,8 +119,9 @@ def spike_lu_sharded(
     # assembly ops lower differently over mesh-sharded operands than over
     # single-device ones, which would break the kernel≡mirror bitwise
     # contract.  The solve entry re-shards ``local_lu`` through its own
-    # in_specs, so nothing is lost (a real accelerator mesh would instead
-    # keep the recovery under shard_map and relax the placement).
+    # in_specs, so nothing is lost.  (Without the gather the tail still
+    # solves the system on an 8-device CPU mesh; no accelerator mesh has
+    # run it yet, so the gather stays on every platform.)
     local_lu, wv = jax.device_put((local_lu, wv), jax.devices()[0])
     return core_spike.assemble_spike_factors(
         local_lu, wv, n=arow.shape[0], bw=bw, devices=devices

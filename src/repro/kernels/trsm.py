@@ -30,7 +30,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blocked import pad_identity_tail as _pad_identity_tail
 from repro.core.blocked import strip_trsm as _strip_trsm
+from repro.core.blocked import strip_utrsm as _strip_utrsm
 from repro.core.factorization import equalized_rhs_tile, inverted_dense_sweeps
+
+from . import aligned, interpret_mode, lane_pad, require_interpret, vmem_limit
 
 __all__ = ["solve_vmem", "solve_tiled", "solve_inverted"]
 
@@ -65,10 +68,13 @@ def solve_vmem(
     """Solve ``(LU) x = b`` for packed ``lu`` (n, n) and RHS ``b`` (n,) or
     (n, m); the RHS columns are tiled across the grid.  RHS widths that do
     not divide ``rhs_tile`` are zero-padded to the next tile multiple and
-    sliced back (zero columns solve to zero, so padding is inert)."""
+    sliced back (zero columns solve to zero, so padding is inert).
+    Interpret mode only: the body slices its VMEM value with traced
+    ``dynamic_slice``, which Mosaic refuses."""
     lu = getattr(lu, "packed", lu)  # accept Factorization artifacts
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = require_interpret(
+        "trsm.solve_vmem", "value-level dynamic_slice in the kernel body", interpret
+    )
     squeeze = b.ndim == 1
     bm = b[:, None] if squeeze else b
     n, m = bm.shape
@@ -91,36 +97,44 @@ def solve_vmem(
     return x[:, 0] if squeeze else x
 
 
-def _solve_tiled_kernel(lu_any, b_ref, x_ref, ltile, sem, *, num_steps: int, block: int):
+def _solve_tiled_kernel(lu_any, b_any, x_any, xbuf, ltile, sem, *, num_steps: int, block: int):
     """One RHS tile program: blocked forward then backward substitution with
-    the LU factor streamed tile-by-tile from HBM."""
+    the LU factor streamed tile-by-tile from HBM.  The program's ``(N, rt)``
+    RHS columns are DMA'd into VMEM scratch once, solved in place, and
+    written back (``x_any`` aliases ``b_any``)."""
+    del b_any  # aliased to x_any
     S, B = num_steps, block
-    rt = b_ref.shape[1]
-    x_ref[...] = b_ref[...]
-    rows_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
-    acc_dtype = jnp.promote_types(jnp.float32, b_ref.dtype)  # f32, or f64 under x64
+    rt = xbuf.shape[1]  # a 128 multiple whenever there are several column tiles
+    cols = x_any.at[:, pl.ds(pl.multiple_of(pl.program_id(0) * rt, 128), rt)]
+    acc_dtype = jnp.promote_types(jnp.float32, xbuf.dtype)  # f32, or f64 under x64
 
-    def load(i, j):
-        dma = pltpu.make_async_copy(
-            lu_any.at[pl.ds(i * B, B), pl.ds(j * B, B)], ltile, sem
-        )
+    def rows(i):
+        return pl.ds(aligned(i * B, B), B)
+
+    def copy(src, dst):
+        dma = pltpu.make_async_copy(src, dst, sem)
         dma.start()
         dma.wait()
 
+    def load(i, j):
+        copy(lu_any.at[pl.ds(i * B, B), pl.ds(j * B, B)], ltile)
+
+    def retire(r, yi):
+        load(r, yi[1])
+        blk = xbuf[rows(r), :]
+        xbuf[rows(r), :] = blk - jnp.dot(
+            ltile[...], yi[0], precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=acc_dtype,
+        ).astype(blk.dtype)
+        return yi
+
+    copy(cols, xbuf)
+
     def fwd_outer(i, _):
         load(i, i)
-        yi = _strip_trsm(ltile[...], x_ref[pl.ds(i * B, B), :])
-        x_ref[pl.ds(i * B, B), :] = yi
-
-        def off(r, _):
-            load(r, i)
-            blk = x_ref[pl.ds(r * B, B), :]
-            x_ref[pl.ds(r * B, B), :] = blk - jnp.dot(
-                ltile[...], yi, preferred_element_type=acc_dtype
-            ).astype(blk.dtype)
-            return 0
-
-        jax.lax.fori_loop(i + 1, S, off, 0)
+        yi = _strip_trsm(ltile[...], xbuf[rows(i), :])
+        xbuf[rows(i), :] = yi
+        jax.lax.fori_loop(i + 1, S, retire, (yi, i))
         return 0
 
     jax.lax.fori_loop(0, S, fwd_outer, 0)
@@ -128,32 +142,17 @@ def _solve_tiled_kernel(lu_any, b_ref, x_ref, ltile, sem, *, num_steps: int, blo
     def bwd_outer(jj, _):
         i = (S - 1) - jj
         load(i, i)
-        u11 = ltile[...]
-        xi = x_ref[pl.ds(i * B, B), :]
-
-        def bwd_in(kk, x):
-            k = (B - 1) - kk
-            pivot = jax.lax.dynamic_slice(u11, (k, k), (1, 1))
-            xk = jax.lax.dynamic_slice(x, (k, 0), (1, rt)) / pivot
-            x = jax.lax.dynamic_update_slice(x, xk, (k, 0))
-            uk = jnp.where(rows_b < k, jax.lax.dynamic_slice(u11, (0, k), (B, 1)), 0.0)
-            return x - uk * xk
-
-        xi = jax.lax.fori_loop(0, B, bwd_in, xi)
-        x_ref[pl.ds(i * B, B), :] = xi
-
-        def off(r, _):
-            load(r, i)
-            blk = x_ref[pl.ds(r * B, B), :]
-            x_ref[pl.ds(r * B, B), :] = blk - jnp.dot(
-                ltile[...], xi, preferred_element_type=acc_dtype
-            ).astype(blk.dtype)
-            return 0
-
-        jax.lax.fori_loop(0, i, off, 0)
+        xi = _strip_utrsm(ltile[...], xbuf[rows(i), :])
+        xbuf[rows(i), :] = xi
+        jax.lax.fori_loop(0, i, retire, (xi, i))
         return 0
 
     jax.lax.fori_loop(0, S, bwd_outer, 0)
+    copy(xbuf, cols)
+
+
+# VMEM the tiled solve may spend on its resident (N, rt) RHS columns.
+_SOLVE_TILED_X_BYTES = 16 * 2**20
 
 
 @functools.partial(jax.jit, static_argnames=("block", "rhs_tile", "interpret"))
@@ -169,12 +168,13 @@ def solve_tiled(
 
     Pads ``n`` to a multiple of ``block`` with an identity tail (inert: unit
     diagonal, zero coupling) and the RHS with zero rows/columns, then runs one
-    program per RHS column tile.  Only one ``(block, block)`` LU tile is
-    on-chip at a time, so the solve scales to matrices far past what
-    :func:`solve_vmem` can hold (~4096² fp32)."""
+    program per RHS column tile.  Only one ``(block, block)`` LU tile and the
+    program's ``(N, rt)`` RHS columns are on-chip at a time, so the solve
+    scales to matrices far past what :func:`solve_vmem` can hold (~4096²
+    fp32); ``rt`` shrinks (in 128-lane steps) to keep the columns within
+    16 MB of VMEM."""
     lu = getattr(lu, "packed", lu)  # accept Factorization artifacts
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode(interpret)
     squeeze = b.ndim == 1
     bm = b[:, None] if squeeze else b
     out_dtype = bm.dtype
@@ -188,8 +188,13 @@ def solve_tiled(
     B = min(block, n)
     S = -(-n // B)
     N = S * B
-    rt = min(rhs_tile, m)
-    M = -(-m // rt) * rt
+    itemsize = jnp.dtype(compute_dtype).itemsize
+    mw = lane_pad(bm[:1]).shape[1]  # whole lane tiles
+    lanes = max(128, _SOLVE_TILED_X_BYTES // (N * itemsize) // 128 * 128)
+    rt = min(rhs_tile, mw, lanes)
+    if rt < mw:  # several column tiles: each must start on a 128-lane boundary
+        rt = max(128, rt // 128 * 128)
+    M = -(-mw // rt) * rt
     lu = _pad_identity_tail(lu, N)
     if (N, M) != (n, m):
         bm = jnp.pad(bm, ((0, N - n), (0, M - m)))
@@ -197,15 +202,20 @@ def solve_tiled(
         functools.partial(_solve_tiled_kernel, num_steps=S, block=B),
         grid=(M // rt,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((N, rt), lambda j: (0, j)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((N, rt), lambda j: (0, j)),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((N, M), bm.dtype),
         scratch_shapes=[
+            pltpu.VMEM((N, rt), compute_dtype),
             pltpu.VMEM((B, B), compute_dtype),
             pltpu.SemaphoreType.DMA,
         ],
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit((N * max(rt, 128) + B * B) * itemsize)
+        ),
         interpret=interpret,
     )(lu, bm)
     x = x[:n, :m].astype(out_dtype)
@@ -266,9 +276,12 @@ def solve_inverted(
     (:func:`repro.core.factorization.equalized_rhs_tile`), sized for the
     wide stacked-RHS dispatches the solve service coalesces.
     Bitwise-identical to
-    :func:`repro.core.factorization.dense_inverted_solve`."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    :func:`repro.core.factorization.dense_inverted_solve`.  Interpret mode
+    only: the shared sweeps slice the carried RHS value with traced
+    ``dynamic_slice``, which Mosaic refuses."""
+    interpret = require_interpret(
+        "trsm.solve_inverted", "value-level dynamic_slice in the shared sweeps", interpret
+    )
     squeeze = b.ndim == 1
     bm = b[:, None] if squeeze else b
     out_dtype = bm.dtype
@@ -286,9 +299,9 @@ def solve_inverted(
         functools.partial(_solve_inverted_kernel, num_steps=S, block=B),
         grid=(M // rt,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((N, rt), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((N, rt), lambda j: (0, j)),

@@ -13,13 +13,7 @@ import jax
 
 
 def _make(shape, axes) -> jax.sharding.Mesh:
-    # axis_types only exists on newer jax; Auto is the default there anyway.
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -43,7 +37,21 @@ def parse_mesh_arg(arg: str) -> jax.sharding.Mesh:
     return make_mesh(dims, names)
 
 
-# v5e hardware constants used by the roofline analysis (EXPERIMENTS.md).
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s inter-chip interconnect = 4 links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_s": 819e9, "ici_link_bytes_s": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of ``device_kind``; a device missing from the table is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks recorded for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None

@@ -17,7 +17,11 @@ import json
 import os
 
 from repro.configs.base import ARCH_IDS, SHAPE_CELLS, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import peaks
+
+# The dry-run compiles for a described v5e pod, so its estimate uses the
+# peaks of that target device kind (not of whatever host runs the script).
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "artifacts", "dryrun")
 
@@ -82,9 +86,10 @@ def analyze(mesh_name: str, out_dir: str):
                 continue
             c = rec["cost"]
             devices = rec["devices"]
-            t_comp = c["flops_per_device"] / PEAK_FLOPS_BF16
-            t_mem = c["bytes_per_device"] / HBM_BW
-            t_coll = c["wire_bytes_per_device"] / ICI_BW
+            pk = peaks(TARGET_DEVICE_KIND)
+            t_comp = c["flops_per_device"] / pk["flops_bf16"]
+            t_mem = c["bytes_per_device"] / pk["hbm_bytes_s"]
+            t_coll = c["wire_bytes_per_device"] / pk["ici_link_bytes_s"]
             terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
             dominant = max(terms, key=terms.get)
             mf = model_flops(cfg, cell, total, active)
@@ -93,7 +98,7 @@ def analyze(mesh_name: str, out_dir: str):
             # roofline fraction: useful work at peak vs the bound set by the
             # dominant term
             step_time = max(terms.values())
-            frac = (mf / devices / PEAK_FLOPS_BF16) / step_time if step_time else 0.0
+            frac = (mf / devices / pk["flops_bf16"]) / step_time if step_time else 0.0
             rows.append({
                 "arch": arch, "cell": cell_name, "status": "ok",
                 "compute_s": t_comp, "memory_s": t_mem, "collective_s": t_coll,

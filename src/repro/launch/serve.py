@@ -8,6 +8,21 @@ import os
 import time
 
 
+def serve_max_len(cfg, *, prompt_len: int, bucket: int, new_tokens: int) -> int:
+    """KV length one request can occupy: its prompt padded to the bucket,
+    its new tokens, the config's prefix embeddings, and a small margin."""
+    return prompt_len + bucket + new_tokens + cfg.num_prefix_embeds + 8
+
+
+def build_engine(params, cfg, *, prompt_len: int, new_tokens: int, slots: int,
+                 bucket: int, **engine_kw):
+    """The serving engine this launcher drives (``engine_kw``: paged mode)."""
+    from repro.serve.engine import Engine
+
+    max_len = serve_max_len(cfg, prompt_len=prompt_len, bucket=bucket, new_tokens=new_tokens)
+    return Engine(params, cfg, max_len=max_len, slots=slots, bucket=bucket, **engine_kw)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3_8b")
@@ -51,9 +66,12 @@ def main():
     from repro.dist import sharding as shlib
     from repro.launch.mesh import parse_mesh_arg
     from repro.models import lm
-    from repro.serve.engine import Engine, GenRequest
+    from repro.serve.engine import GenRequest
+    from repro.utils.compile_cache import enable_compilation_cache
 
     import jax
+
+    enable_compilation_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -72,7 +90,8 @@ def main():
             tokens=rng.integers(0, cfg.vocab_size, (s0,)).astype(np.int32),
             max_new_tokens=nt, temperature=args.temperature, seed=args.seed + i,
         ))
-    max_len = args.prompt_len + args.bucket + args.new_tokens + cfg.num_prefix_embeds + 8
+    max_len = serve_max_len(cfg, prompt_len=args.prompt_len, bucket=args.bucket,
+                            new_tokens=args.new_tokens)
 
     paged_kw = {}
     if args.paged:
@@ -114,8 +133,9 @@ def main():
                         shards=args.shards)
 
     def serve():
-        eng = Engine(params, cfg, max_len=max_len, slots=args.slots,
-                     bucket=args.bucket, **paged_kw)
+        eng = build_engine(params, cfg, prompt_len=args.prompt_len,
+                           new_tokens=args.new_tokens, slots=args.slots,
+                           bucket=args.bucket, **paged_kw)
         t0 = time.perf_counter()
         outs = eng.serve(reqs)
         return eng, outs, time.perf_counter() - t0

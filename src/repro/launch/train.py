@@ -37,6 +37,9 @@ def main():
         )
 
     import jax
+    from repro.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     from repro.configs.base import get_config
     from repro.dist import sharding as shlib
     from repro.launch.mesh import make_production_mesh, parse_mesh_arg

@@ -234,20 +234,17 @@ class Engine:
         """Autotuned page size when `scripts/autotune.py` has measured a
         transferable sweep (op="decode", structure="paged_kv"); 16 outside
         measured territory."""
-        try:
-            from repro.solvers.cache import get_cache
-            from repro.solvers.problem import Problem
+        from repro.solvers.cache import get_cache
+        from repro.solvers.problem import Problem
 
-            best = get_cache().best_page_size(
-                Problem(
-                    op="decode", structure="paged_kv", n=max_len,
-                    dtype=jnp.dtype(self.cfg.dtype).name,
-                )
+        best = get_cache().best_page_size(
+            Problem(
+                op="decode", structure="paged_kv", n=max_len,
+                dtype=jnp.dtype(self.cfg.dtype).name,
             )
-            if best:
-                return int(best)
-        except Exception:
-            pass
+        )
+        if best:  # None: no transferable measurement for this shape
+            return int(best)
         return 16
 
     def paged_capacity_slots(self, pages_per_request: int | None = None) -> int:

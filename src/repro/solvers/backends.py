@@ -52,9 +52,8 @@ __all__ = [
 SOLVE_VMEM_MAX_N = 2048
 
 # Above this many skewed-band bytes the static banded choice switches from
-# the VMEM-resident blocked kernel to the HBM-streaming tiled kernel (the
-# VMEM kernel holds the skewed band twice — in and out — on real TPUs).
-BANDED_VMEM_MAX_BYTES = 6 * 2**20
+# the VMEM-resident blocked kernel to the HBM-streaming tiled kernel.
+BANDED_VMEM_MAX_BYTES = _core_banded.BANDED_VMEM_MAX_BYTES
 
 # Largest per-system order the batched grid kernels keep VMEM-resident
 # ((n, n) matrix + (n, m) RHS per grid program).
@@ -92,6 +91,13 @@ def _local(p: Problem) -> bool:
     return p.devices == 1
 
 
+def _off_tpu(p: Problem) -> bool:
+    """Capability clause of kernels Mosaic does not lower: they run only in
+    interpret mode, so a TPU dispatch must never select them (a forced
+    ``impl=`` of one raises from the kernel entry instead)."""
+    return not p.tpu
+
+
 def _banded_skew_bytes(p: Problem, block: int | None = None) -> int:
     c = _core_banded.band_block_size(p.n, p.bw, block)
     return _core_banded.skew_rows(p.n, p.bw, c) * (c + 2 * p.bw) * _itemsize(p)
@@ -99,9 +105,8 @@ def _banded_skew_bytes(p: Problem, block: int | None = None) -> int:
 
 def banded_static_impl(n: int, bw: int, block: int | None, itemsize: int) -> str:
     """The historical banded auto rule (kept callable for the shim/tests)."""
-    c = _core_banded.band_block_size(n, bw, block)
-    skew_bytes = _core_banded.skew_rows(n, bw, c) * (c + 2 * bw) * itemsize
-    return "pallas_blocked" if skew_bytes <= BANDED_VMEM_MAX_BYTES else "pallas_tiled"
+    takes = _core_banded.blocked_kernel_takes(n, bw, block, itemsize, compiled=False)
+    return "pallas_blocked" if takes else "pallas_tiled"
 
 
 # ---------------------------------------------------------------------------
@@ -265,7 +270,8 @@ register(Backend(
 register(Backend(
     name="pallas_vmem", op="factor", structure="dense",
     call=lambda p, a, *, interpret=None, **_: _k.lu_vmem(a, interpret=interpret),
-    supports=lambda p: _is_f32(p) and _local(p) and p.n <= 4096,
+    # off TPU: Mosaic refuses the value-level dynamic_slice in its body
+    supports=lambda p: _is_f32(p) and _local(p) and p.n <= 4096 and _off_tpu(p),
     priority=lambda p: 1.0,
     autotune=False,  # not value-identical to the fused/xla twins
     vmem_bytes=lambda p: 2 * p.n * p.n * _itemsize(p),
@@ -274,7 +280,8 @@ register(Backend(
     name="pallas_blocked", op="factor", structure="dense",
     call=lambda p, a, *, block=256, col_tile=256, interpret=None, **_:
         _pallas_blocked_lu(a, block=block, col_tile=col_tile, interpret=interpret),
-    supports=_local,
+    # off TPU: Mosaic refuses the panel/fused_step kernels' value-level dynamic_slice
+    supports=lambda p: _local(p) and _off_tpu(p),
     priority=lambda p: 0.0,
     autotune=False,  # dominated multi-launch legacy driver (forced-impl only)
 ))
@@ -304,7 +311,8 @@ register(Backend(
     name="pallas_vmem", op="solve", structure="dense",
     call=lambda p, lu, b, *, rhs_tile=256, interpret=None, **_:
         _trsm.solve_vmem(_packed(lu), b, rhs_tile=rhs_tile, interpret=interpret),
-    supports=_local,
+    # off TPU: Mosaic refuses the value-level dynamic_slice in its body
+    supports=lambda p: _local(p) and _off_tpu(p),
     priority=lambda p: 3.0 if p.n <= SOLVE_VMEM_MAX_N else 0.0,
     vmem_bytes=lambda p: (p.n * p.n + p.n * max(p.rhs, 1)) * _itemsize(p),
 ))
@@ -312,7 +320,8 @@ register(Backend(
     name="pallas_tiled", op="solve", structure="dense",
     call=lambda p, lu, b, *, block=256, rhs_tile=256, interpret=None, **_:
         _trsm.solve_tiled(_packed(lu), b, block=block, rhs_tile=rhs_tile, interpret=interpret),
-    supports=_local,
+    # on TPU the (B, B) factor tiles must be lane-aligned: B = min(256, n)
+    supports=lambda p: _local(p) and (_off_tpu(p) or p.n >= 256 or p.n % 128 == 0),
     priority=lambda p: 1.0,
 ))
 register(Backend(
@@ -323,7 +332,8 @@ register(Backend(
     # from ever steering a raw operand here).
     call=lambda p, lu, b, *, block=None, rhs_tile=512, interpret=None, **_:
         _dense_inverted_call(lu, b, block=block, rhs_tile=rhs_tile, interpret=interpret),
-    supports=lambda p: _local(p) and p.enriched,
+    # off TPU: Mosaic refuses the shared sweeps' value-level dynamic_slice
+    supports=lambda p: _local(p) and p.enriched and _off_tpu(p),
     priority=lambda p: 0.75,  # below the defaults: reach it measured or forced
     autotune=False,  # not value-identical to the strip-recurrence twins
     vmem_bytes=lambda p: (2 * p.n * 256 + p.n * max(p.rhs, 1)) * _itemsize(p),
@@ -361,7 +371,8 @@ register(Backend(
     name="pallas_blocked", op="factor", structure="banded",
     call=lambda p, arow, *, bw, block=None, interpret=None, **_:
         _kbanded.banded_lu_blocked(arow, bw=bw, block=block, interpret=interpret),
-    supports=_local,
+    supports=lambda p: _local(p) and (_off_tpu(p) or _core_banded.blocked_kernel_takes(
+        p.n, p.bw, None, _itemsize(p), compiled=True)),
     priority=lambda p: 3.0 if _banded_skew_bytes(p) <= BANDED_VMEM_MAX_BYTES else 0.0,
     vmem_bytes=lambda p: 2 * _banded_skew_bytes(p),
 ))
@@ -382,7 +393,8 @@ register(Backend(
     name="pallas_scalar", op="factor", structure="banded",
     call=lambda p, arow, *, bw, interpret=None, **_:
         _kbanded.banded_lu_kernelized(arow, bw=bw, interpret=interpret),
-    supports=_local,
+    # off TPU: Mosaic refuses the value-level dynamic_slice in its body
+    supports=lambda p: _local(p) and _off_tpu(p),
     priority=lambda p: 0.2,
     autotune=False,  # legacy scalar-sequential kernel (forced-impl only)
 ))
@@ -414,7 +426,8 @@ register(Backend(
     # keeps raw-operand dispatches from paying the on-the-fly enrichment.
     call=lambda p, lub, b, *, bw, block=None, rhs_tile=512, interpret=None, **_:
         _banded_inverted_call(lub, b, bw=bw, block=block, rhs_tile=rhs_tile, interpret=interpret),
-    supports=lambda p: _local(p) and p.enriched,
+    # off TPU: Mosaic refuses the in-kernel lax.associative_scan
+    supports=lambda p: _local(p) and p.enriched and _off_tpu(p),
     priority=lambda p: 1.5,
     vmem_bytes=_banded_inverted_vmem_bytes,
 ))
@@ -458,7 +471,8 @@ register(Backend(
 register(Backend(
     name="pallas_vmem", op="factor", structure="batched_dense",
     call=lambda p, a, *, interpret=None, **_: _kbatched.batched_lu_vmem(a, interpret=interpret),
-    supports=lambda p: _is_f32(p) and _local(p) and p.n <= BATCHED_VMEM_MAX_N,
+    # off TPU: Mosaic refuses the value-level dynamic_slice in its body
+    supports=lambda p: _is_f32(p) and _local(p) and p.n <= BATCHED_VMEM_MAX_N and _off_tpu(p),
     priority=lambda p: 2.0,
     vmem_bytes=lambda p: 2 * p.n * p.n * _itemsize(p),  # per grid program
 ))
@@ -474,8 +488,9 @@ register(Backend(
     # in VMEM next to the (n, n) factors, so a wide coalesced stack must
     # overflow to the vmapped mirror rather than the kernel.
     call=lambda p, lu, b, *, interpret=None, **_: _kbatched.batched_lu_solve_vmem(_packed(lu), b, interpret=interpret),
+    # off TPU: Mosaic refuses the value-level dynamic_slice in its body
     supports=lambda p: _is_f32(p) and _local(p) and p.n <= BATCHED_VMEM_MAX_N
-        and max(p.rhs, 1) <= 4 * p.n,
+        and max(p.rhs, 1) <= 4 * p.n and _off_tpu(p),
     priority=lambda p: 2.0,
     vmem_bytes=lambda p: (2 * p.n * p.n + 2 * p.n * max(p.rhs, 1)) * _itemsize(p),
 ))
@@ -504,7 +519,9 @@ register(Backend(
     name="pallas_vmem", op="factor", structure="batched_banded",
     call=lambda p, arow, *, bw, block=None, interpret=None, **_:
         _kbanded.batched_banded_lu_vmem(arow, bw=bw, block=block, interpret=interpret),
-    supports=lambda p: _is_f32(p) and _local(p) and _banded_skew_bytes(p) <= BANDED_VMEM_MAX_BYTES,
+    # off TPU: per-system row offsets are not provably sublane-aligned
+    supports=lambda p: _is_f32(p) and _local(p) and _off_tpu(p)
+        and _banded_skew_bytes(p) <= BANDED_VMEM_MAX_BYTES,
     priority=lambda p: 2.0,
     vmem_bytes=lambda p: 2 * _banded_skew_bytes(p),
 ))
@@ -520,7 +537,8 @@ register(Backend(
     # band, so both must fit under the banded byte cap.
     call=lambda p, lub, b, *, bw, block=None, interpret=None, **_:
         _kbanded.batched_banded_solve_vmem(_packed(lub), b, bw=bw, block=block, interpret=interpret),
-    supports=lambda p: _is_f32(p) and _local(p)
+    # off TPU: Mosaic refuses the sweeps' value-level dynamic_slice
+    supports=lambda p: _is_f32(p) and _local(p) and _off_tpu(p)
         and _banded_skew_bytes(p) + 2 * p.n * max(p.rhs, 1) * _itemsize(p)
             <= BANDED_VMEM_MAX_BYTES,
     priority=lambda p: 2.0,

@@ -56,20 +56,23 @@ _VERSION = 1
 # ``devices`` is likewise a key field: the single-device and mesh-sharded
 # candidate sets are disjoint (SPIKE vs replication), so a single-device
 # measured win must never steer a multi-device dispatch or vice versa
-# (pre-devices caches load as devices-1 == local rows).
-_KEY_FIELDS = ("op", "structure", "dtype", "bw", "n", "tolerance", "devices")
+# (pre-devices caches load as devices-1 == local rows).  ``device_kind`` is
+# a key field too: a timing taken on the CPU (Pallas in interpret mode) says
+# nothing about a TPU, so rows persisted before the field existed load as
+# CPU rows and never steer a chip's dispatch.
+_KEY_FIELDS = ("op", "structure", "dtype", "bw", "n", "tolerance", "devices", "device_kind")
 
 
 def cache_path() -> str:
     return os.path.expanduser(os.environ.get(ENV_VAR) or DEFAULT_USER_PATH)
 
 
-_KEY_DEFAULTS = {"tolerance": 0.0, "devices": 1}
+_KEY_DEFAULTS = {"tolerance": 0.0, "devices": 1, "device_kind": "cpu"}
 
 
 def _entry_key(e: dict) -> tuple:
-    # entries built by hand (tests, old tools) may omit tolerance == exact
-    # and devices == 1 (single-device)
+    # entries built by hand (tests, old tools) may omit tolerance == exact,
+    # devices == 1 (single-device) and device_kind == "cpu"
     return tuple(
         e.get(f, _KEY_DEFAULTS[f]) if f in _KEY_DEFAULTS else e[f]
         for f in _KEY_FIELDS
@@ -77,7 +80,8 @@ def _entry_key(e: dict) -> tuple:
 
 
 def _problem_key(p: Problem) -> tuple:
-    return (p.op, p.structure, p.dtype, p.bw, p.n, float(p.tolerance), int(p.devices))
+    return (p.op, p.structure, p.dtype, p.bw, p.n, float(p.tolerance), int(p.devices),
+            p.device_kind)
 
 
 class AutotuneCache:
@@ -97,6 +101,7 @@ class AutotuneCache:
             for e in raw.get("entries", []):
                 e.setdefault("tolerance", 0.0)  # pre-tolerance caches = exact rows
                 e.setdefault("devices", 1)  # pre-devices caches = local rows
+                e.setdefault("device_kind", "cpu")  # pre-device-kind caches = CPU rows
                 if all(f in e for f in _KEY_FIELDS) and isinstance(e.get("times_us"), dict):
                     entries.append(e)
         except FileNotFoundError:
@@ -194,17 +199,20 @@ class AutotuneCache:
     def _matches(self, problem: Problem) -> list[tuple[float, dict]]:
         out = []
         for e in self.entries:
-            # exact match on every non-size key — in particular tolerance
-            # and devices: nearest-size transfer interpolates over *speed*,
-            # never over *accuracy tier* (a loose-tolerance win must not
-            # leak into a tight dispatch) nor over *device count* (the
-            # single-device and mesh-sharded candidate sets are disjoint).
+            # exact match on every non-size key — in particular tolerance,
+            # devices and device kind: nearest-size transfer interpolates
+            # over *speed*, never over *accuracy tier* (a loose-tolerance
+            # win must not leak into a tight dispatch), *device count* (the
+            # single-device and mesh-sharded candidate sets are disjoint) or
+            # *device kind* (a CPU interpret-mode timing is no TPU timing).
             if (
                 e["op"], e["structure"], e["dtype"],
                 e.get("tolerance", 0.0), e.get("devices", 1),
+                e.get("device_kind", "cpu"),
             ) != (
                 problem.op, problem.structure, problem.dtype,
                 float(problem.tolerance), int(problem.devices),
+                problem.device_kind,
             ):
                 continue
             n_ratio = max(e["n"], problem.n) / max(min(e["n"], problem.n), 1)

@@ -11,9 +11,10 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["Problem", "OPS", "STRUCTURES"]
+__all__ = ["Problem", "OPS", "STRUCTURES", "default_device_kind", "is_tpu"]
 
 OPS = ("factor", "solve", "linear_solve", "decode")
 STRUCTURES = ("dense", "banded", "batched_dense", "batched_banded", "paged_kv")
@@ -47,6 +48,12 @@ class Problem:
                  Defaults True (the steady-state serving operand is an
                  enriched artifact); ``from_arrays`` downgrades it for raw
                  arrays.  Deliberately NOT part of the autotune cache key.
+    ``device_kind`` ``jax.Device.device_kind`` of the device the call runs
+                 on (``"cpu"``, ``"TPU v5 lite"``, ...); defaults to the
+                 first device of the default backend.  Capability
+                 predicates reject kernels Mosaic cannot lower when it names
+                 a TPU, and the autotune cache keys on it so a timing taken
+                 on one device kind never steers another.
     """
 
     op: str
@@ -60,8 +67,11 @@ class Problem:
     tolerance: float = 0.0
     verify_residual: bool = False
     enriched: bool = True
+    device_kind: str = ""
 
     def __post_init__(self):
+        if not self.device_kind:
+            object.__setattr__(self, "device_kind", default_device_kind())
         if self.op not in OPS:
             raise ValueError(f"unknown op {self.op!r} (expected one of {OPS})")
         if self.structure not in STRUCTURES:
@@ -70,6 +80,10 @@ class Problem:
             )
         if self.tolerance < 0:
             raise ValueError(f"tolerance must be >= 0, got {self.tolerance}")
+
+    @property
+    def tpu(self) -> bool:
+        return is_tpu(self.device_kind)
 
     @property
     def banded(self) -> bool:
@@ -123,3 +137,12 @@ class Problem:
             verify_residual=bool(verify_residual),
             enriched=enriched,
         )
+
+
+def default_device_kind() -> str:
+    """Device kind of the first device of the default backend."""
+    return jax.devices()[0].device_kind
+
+
+def is_tpu(device_kind: str) -> bool:
+    return device_kind.lower().startswith("tpu")

@@ -30,16 +30,20 @@ dispatch carries a *validator* — a factor health screen from
 ``ops.lu(..., health=)``, the built-in relative-residual check armed by
 ``Problem.verify_residual``, or an injected fault plan
 (:mod:`repro.solvers.faults`) — an auto-selected dispatch becomes a retry
-loop over the capable candidates, best-first: a backend whose call raises
-or whose result fails validation is *demoted* for that problem shape
+loop over the capable candidates, best-first: a backend whose result fails
+validation, or whose call raises an operand fault (an arithmetic or
+linear-algebra error, a nested :class:`SolveFailure`, an injected fault),
+is *demoted* for that problem shape
 (skipped for the next ``DEMOTION_TTL`` same-shape dispatches), an
 escalation event fires (``add_escalation_hook`` / ``record_escalations``),
 and the next candidate runs.  The last resort for dense factors is the
 partial-pivoting ``pivoted`` backend (:mod:`repro.core.pivoted`) registered
 at the lowest priority.  When every candidate fails, the dispatch raises a
 structured :class:`SolveFailure` carrying the problem, the per-backend
-escalation chain, and the final health record — never NaN factors.  A
-default dispatch (no validator, no active faults, no demotions) takes the
+escalation chain, and the final health record — never NaN factors.  Any
+other exception (a kernel the compiler refuses, a bad block shape, a VMEM
+overflow, a bug) propagates: it is not the operand's fault, and serving the
+next candidate would hide it.  A default dispatch (no validator, no active faults, no demotions) takes the
 exact pre-funnel fast path, so default selection and results stay
 bitwise-identical.
 """
@@ -47,6 +51,8 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Callable
+
+import numpy as np
 
 from . import cache as _cache
 from .problem import Problem
@@ -430,6 +436,8 @@ def dispatch(
         return backend.call(problem, *arrays, **kw)
 
     # --- escalation funnel -------------------------------------------------
+    operand_faults = (ArithmeticError, np.linalg.LinAlgError, SolveFailure,
+                      _faults.InjectedFault)
     winner = select(problem, cache=cache, allow=allow)
     rest = sorted(
         (b for b in candidates(problem, allow=allow) if b.name != winner.name),
@@ -451,7 +459,10 @@ def dispatch(
             if err is None:
                 return result
             reason, health = err
-        except Exception as e:  # noqa: BLE001 — every backend error escalates
+        except operand_faults as e:
+            # only a fault of the operand escalates; any other exception (a
+            # kernel Mosaic refuses, a block shape, a VMEM overflow, a bug)
+            # propagates instead of quietly serving the next candidate
             reason = f"{type(e).__name__}: {e}"
         last_health = health if health is not None else last_health
         chain.append({"backend": backend.name, "reason": reason})

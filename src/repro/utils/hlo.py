@@ -185,7 +185,7 @@ def primitive_count(jaxpr, name: str) -> int:
     into sub-jaxprs (cond/scan/while/pjit bodies).  Used to assert dispatch
     counts — e.g. the single-dispatch LU driver must trace to exactly one
     ``pallas_call``."""
-    from jax.core import Jaxpr, ClosedJaxpr  # local: keep module import-light
+    from jax.extend.core import ClosedJaxpr, Jaxpr  # local: keep module import-light
 
     if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
@@ -201,10 +201,5 @@ def primitive_count(jaxpr, name: str) -> int:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """jax-version-portable ``Compiled.cost_analysis()``: newer jax returns a
-    flat dict, older releases a one-element list of dicts (per device
-    assignment).  Always returns the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())

@@ -354,11 +354,16 @@ def test_eos_works_in_dense_mode_mixed_batch(setup, dense_engine):
     p1 = rng.integers(0, cfg.vocab_size, (6,)).astype(np.int32)
     p2 = rng.integers(0, cfg.vocab_size, (9,)).astype(np.int32)
     base = dense_engine.serve([GenRequest(p1, 8, seed=1), GenRequest(p2, 8, seed=2)])
-    eos_tok = int(base[0][len(p1) + 1])  # second generated token of req 1
+    # eos = the first generated token of req 1 (past the first) that has not
+    # appeared earlier in its output: the output truncates at the FIRST
+    # occurrence, so a repeated token would end it sooner
+    gen = base[0][len(p1):].tolist()
+    k = next(i for i in range(1, len(gen)) if gen[i] not in gen[:i])
+    eos_tok = int(gen[k])
     eng = Engine(params, cfg, max_len=64, slots=2, bucket=4, eos_poll=1)
     outs = eng.serve([GenRequest(p1, 8, seed=1, eos_token=eos_tok),
                       GenRequest(p2, 8, seed=2)])
-    np.testing.assert_array_equal(outs[0], base[0][: len(p1) + 2])
+    np.testing.assert_array_equal(outs[0], base[0][: len(p1) + k + 1])
     np.testing.assert_array_equal(outs[1], base[1])
     assert eng.stats.early_exits == 1
 

@@ -2,10 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container lacks hypothesis: seeded deterministic shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     banded_lu_solve,
