@@ -43,6 +43,7 @@ automatically; on TPU they lower to Mosaic.
 """
 from __future__ import annotations
 
+import functools
 import warnings
 
 import importlib
@@ -55,6 +56,7 @@ from repro.core.factorization import Factorization, factorize_banded, factorize_
 from repro.core.pivoted import PivotedFactors
 from repro.core.randomized import RankKFactors
 from repro.core.spike import SpikeFactors
+from repro.utils import spans
 
 __all__ = [
     "lu",
@@ -80,6 +82,39 @@ def __getattr__(name: str):
     if name == "_BANDED_VMEM_MAX_BYTES":
         return _sol().backends.BANDED_VMEM_MAX_BYTES
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+def _spanned(name: str, attrs):
+    """Record each call of the op as the program span ``name`` with
+    ``attrs(*args, **kw)`` (:mod:`repro.utils.spans`).  The span ends when
+    the op returns, once its kernels are enqueued: it is the host time
+    before the device can start.  Off, it costs one check."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def op(*args, **kw):
+            if not spans.active():
+                return fn(*args, **kw)
+            with spans.span(name, **attrs(*args, **kw)):
+                return fn(*args, **kw)
+
+        return op
+
+    return wrap
+
+
+def _factor_attrs(a, *, bw: int = 0, **_) -> dict:
+    n = a.shape[-2] if bw else a.shape[-1]
+    return {"n": int(n), "bw": int(bw), "eager": not isinstance(a, jax.core.Tracer)}
+
+
+def _solve_attrs(factors, b, *, bw: int = 0, **_) -> dict:
+    vector = b.ndim == getattr(factors, "ndim", 2) - 1
+    return {"n": int(b.shape[-1] if vector else b.shape[-2]), "bw": int(bw),
+            "k": 1 if vector else int(b.shape[-1]),
+            "eager": not isinstance(factors, jax.core.Tracer)
+            and not isinstance(b, jax.core.Tracer)}
+
 
 _FUSED_FALLBACK_WARNED: set[str] = set()
 
@@ -205,6 +240,7 @@ def _lu_batched(a: jax.Array, *, impl, block, interpret, tolerance=0.0,
     )
 
 
+@_spanned("repro.ops.lu", _factor_attrs)
 def lu(
     a: jax.Array,
     *,
@@ -337,6 +373,7 @@ def _lu_solve_batched(lu_packed, b, *, impl, block, interpret, tolerance=0.0):
     return x[..., 0] if squeeze else x
 
 
+@_spanned("repro.ops.lu_solve", _solve_attrs)
 def lu_solve(
     lu_packed,
     b: jax.Array,
@@ -553,6 +590,7 @@ def _banded_lu_batched(arow, *, bw, impl, block, interpret, tolerance=0.0,
     )
 
 
+@_spanned("repro.ops.banded_lu", _factor_attrs)
 def banded_lu(
     arow: jax.Array,
     *,
@@ -666,6 +704,7 @@ def _banded_solve_batched(lu_band, b, *, bw, impl, block, interpret, tolerance=0
     )
 
 
+@_spanned("repro.ops.banded_solve", _solve_attrs)
 def banded_solve(
     lu_band: jax.Array,
     b: jax.Array,

@@ -17,7 +17,7 @@ sharding annotations, then record:
   * the op histogram and compile wall time.
 
 Artifacts: ``artifacts/dryrun/<mesh>/<arch>__<cell>.json`` (cached; --force
-re-runs).  EXPERIMENTS.md §Dry-run and §Roofline are generated from these.
+re-runs).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --mesh single --arch llama3_8b --cell train_4k

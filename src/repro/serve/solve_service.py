@@ -84,9 +84,11 @@ from repro.core.solve import split_rhs, stack_rhs
 from repro.core.spike import SpikeFactors, spike_supported
 from repro.kernels import ops as kops
 from repro.solvers.backends import RAND_LU_RESIDUAL_BOUND
+from repro.utils import spans
 from .scheduler import Scheduler
 
 __all__ = [
+    "FLUSH_COUNTERS",
     "SolveRequest",
     "SolveServiceStats",
     "SolveService",
@@ -118,11 +120,15 @@ class DeadlineMiss:
 def fingerprint(a, *, bw: int = 0) -> str:
     """Content hash identifying a matrix operand (dense or row-aligned
     band): sha1 over the raw bytes + shape + dtype + bandwidth."""
-    arr = np.asarray(a)
-    h = hashlib.sha1()
-    h.update(str((arr.shape, arr.dtype.str, int(bw))).encode())
-    h.update(arr.tobytes())
-    return h.hexdigest()
+    with spans.span("repro.service.fingerprint") as sp:
+        with spans.span("repro.service.fingerprint.to_host"):
+            arr = np.asarray(a)
+        sp.set(bytes=arr.nbytes)
+        with spans.span("repro.service.fingerprint.hash"):
+            h = hashlib.sha1()
+            h.update(str((arr.shape, arr.dtype.str, int(bw))).encode())
+            h.update(arr.tobytes())
+            return h.hexdigest()
 
 
 @dataclasses.dataclass
@@ -160,6 +166,11 @@ class SolveServiceStats:
     def hit_rate(self) -> float:
         tot = self.cache_hits + self.cache_misses
         return self.cache_hits / tot if tot else 0.0
+
+
+# the stats counters whose change over one flush its span records
+FLUSH_COUNTERS = ("cache_hits", "cache_misses", "factor_dispatches", "solve_dispatches",
+                  "escalations", "solved_columns", "failed_requests")
 
 
 class SolveService:
@@ -248,19 +259,20 @@ class SolveService:
                 )
         a = jnp.asarray(a)
         b = jnp.asarray(b)
-        ticket = self._tickets
-        self._tickets += 1
-        req = SolveRequest(
-            ticket=ticket, fp=fingerprint(a, bw=bw), a=a, b=b, bw=bw,
-            deadline=deadline, tolerance=float(tolerance), rank=rank,
-        )
         n = int(a.shape[-2]) if bw else int(a.shape[-1])
         structure = "banded" if bw else "dense"
         cols = 1 if b.ndim == 1 else int(b.shape[-1])
-        self._sched.submit(
-            req, bucket=(structure, n, bw, str(a.dtype), float(tolerance)),
-            cost=float(cols), deadline=deadline, real=cols,
-        )
+        ticket = self._tickets
+        self._tickets += 1
+        with spans.span("repro.service.submit", request=ticket, n=n, bw=bw, cols=cols):
+            req = SolveRequest(
+                ticket=ticket, fp=fingerprint(a, bw=bw), a=a, b=b, bw=bw,
+                deadline=deadline, tolerance=float(tolerance), rank=rank,
+            )
+            self._sched.submit(
+                req, bucket=(structure, n, bw, str(a.dtype), float(tolerance)),
+                cost=float(cols), deadline=deadline, real=cols,
+            )
         self.stats.requests += 1
         self._pending_tickets.add(ticket)
         return ticket
@@ -351,7 +363,20 @@ class SolveService:
         (the request's coalesced group exhausted the escalation funnel, or
         its fingerprint is quarantined), or a :class:`DeadlineMiss` (shed at
         drain — only when the service was built with a ``clock``).  One
-        group failing never disturbs the other groups in the flush."""
+        group failing never disturbs the other groups in the flush.
+
+        While spans are recorded (:mod:`repro.utils.spans`) the flush's span
+        carries the change of each of ``FLUSH_COUNTERS`` over the flush."""
+        with spans.span("repro.service.flush", requests=len(self._sched)) as sp:
+            if not sp:
+                return self._flush(sp)
+            before = [getattr(self.stats, f) for f in FLUSH_COUNTERS]
+            try:
+                return self._flush(sp)
+            finally:
+                sp.set(**{f: getattr(self.stats, f) - b for f, b in zip(FLUSH_COUNTERS, before)})
+
+    def _flush(self, sp) -> dict[int, object]:
         counting = solvers.add_dispatch_hook(self._count_dispatch)
         escalating = solvers.add_escalation_hook(self._count_escalation)
         self._flush_count += 1
@@ -380,6 +405,7 @@ class SolveService:
                 # rank-tier requests coalesce separately from exact requests
                 # against the same matrix — they want different factors.
                 groups.setdefault((p.fp, p.rank), []).append(entry)
+            sp.set(groups=len(groups))
             for (fp, rank), entries in groups.items():
                 reqs = [e.payload for e in entries]
                 quarantined = self._quarantine.get(fp)
@@ -395,7 +421,11 @@ class SolveService:
                 # dispatch: every member accepts its residual.
                 group_tol = min(r.tolerance for r in reqs)
                 try:
-                    factors = self._factors_for(reqs[0], group_tol)
+                    with spans.span("repro.service.factors", fp=fp[:12],
+                                    tickets=[r.ticket for r in reqs]) as fsp:
+                        hits = self.stats.cache_hits
+                        factors = self._factors_for(reqs[0], group_tol)
+                        fsp.set(hit=self.stats.cache_hits > hits)
                     # hit/miss accounting is per REQUEST: coalesced group
                     # members past the leader skip the factorization too
                     self.stats.cache_hits += len(reqs) - 1
@@ -470,34 +500,36 @@ class SolveService:
             return kops.lu_solve(factors, cols, tolerance=tolerance)
 
         width = int(stacked.shape[-1])
-        cap = None
-        if not isinstance(factors, (RankKFactors, PivotedFactors, SpikeFactors)):
-            # width measurements only exist for packed-factor substitution;
-            # rank-k solves are GEMM-shaped and always coalesce fully,
-            # pivoted factors (the escalation last resort) are too rare to
-            # have measured widths, and SPIKE split factors coalesce fully
-            # so the reduced spike system is solved exactly once.
-            problem = solvers.Problem.from_arrays(
-                "solve", factors, stacked, bw=req.bw, tolerance=tolerance
-            )
-            cap = solvers.get_cache().best_width(problem)
-        if cap and width > cap:
-            pieces = [
-                run(stacked[..., i : i + cap]) for i in range(0, width, cap)
-            ]
-            self.stats.width_capped_dispatches += len(pieces) - 1
-            x = jnp.concatenate(pieces, axis=-1)
-        else:
-            x = run(stacked)
-        if isinstance(factors, RankKFactors) and tolerance > 0.0:
-            # polish the approximate-tier answer to the group tolerance
-            # against the full operand; the sweep count lands in stats.
-            x, info = _refine.iterative_refinement(
-                req.a, stacked, x, run, tolerance=tolerance
-            )
-            jax.block_until_ready(x)
-            self.stats.last_refine_iterations = int(info.iterations)
-        return x
+        with spans.span("repro.service.solve", fp=req.fp[:12], width=width) as sp:
+            cap = None
+            if not isinstance(factors, (RankKFactors, PivotedFactors, SpikeFactors)):
+                # width measurements only exist for packed-factor substitution;
+                # rank-k solves are GEMM-shaped and always coalesce fully,
+                # pivoted factors (the escalation last resort) are too rare to
+                # have measured widths, and SPIKE split factors coalesce fully
+                # so the reduced spike system is solved exactly once.
+                problem = solvers.Problem.from_arrays(
+                    "solve", factors, stacked, bw=req.bw, tolerance=tolerance
+                )
+                cap = solvers.get_cache().best_width(problem)
+            if cap and width > cap:
+                pieces = [
+                    run(stacked[..., i : i + cap]) for i in range(0, width, cap)
+                ]
+                self.stats.width_capped_dispatches += len(pieces) - 1
+                x = jnp.concatenate(pieces, axis=-1)
+            else:
+                x = run(stacked)
+            if isinstance(factors, RankKFactors) and tolerance > 0.0:
+                # polish the approximate-tier answer to the group tolerance
+                # against the full operand; the sweep count lands in stats.
+                x, info = _refine.iterative_refinement(
+                    req.a, stacked, x, run, tolerance=tolerance
+                )
+                jax.block_until_ready(x)
+                self.stats.last_refine_iterations = int(info.iterations)
+                sp.set(refine_iterations=int(info.iterations))
+            return x
 
     def result(self, ticket: int):
         """Redeem (pop) a flushed ticket.  Raises :class:`NotFlushed` when

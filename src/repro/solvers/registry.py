@@ -54,6 +54,8 @@ from typing import Callable
 
 import numpy as np
 
+from repro.utils import spans as _spans
+
 from . import cache as _cache
 from .problem import Problem
 
@@ -396,7 +398,19 @@ def dispatch(
     on forced ones.  Validation and the built-in residual check only run
     eagerly; under tracing (jit/vmap rules call dispatch at trace time)
     results pass through unscreened.
+
+    Recorded as the program span ``repro.dispatch`` (:mod:`repro.utils.spans`),
+    with children ``repro.dispatch.select``, ``repro.dispatch.call`` and
+    ``repro.dispatch.validate``; ``traced`` says the operands were tracers.
     """
+    with _spans.span("repro.dispatch", op=problem.op, structure=problem.structure,
+                     n=problem.n, k=problem.rhs) as sp:
+        if sp:
+            sp.set(traced=not _eager(arrays))
+        return _dispatch(sp, problem, arrays, impl, cache, allow, validate, kw)
+
+
+def _dispatch(sp, problem, arrays, impl, cache, allow, validate, kw):
     from . import faults as _faults
 
     plans = _faults.active_plans()
@@ -408,12 +422,16 @@ def dispatch(
         # forced override: no escalation target exists, but faults still
         # apply and a failed validation still raises the structured failure
         # instead of returning a known-bad result.
-        backend = get_backend(problem.op, problem.structure, impl)
+        with _spans.span("repro.dispatch.select"):
+            backend = get_backend(problem.op, problem.structure, impl)
+        sp.set(backend=backend.name, attempts=1)
         for hook in _DISPATCH_HOOKS:
             hook(problem, backend)
-        result = _run_attempt(plans, problem, backend, arrays, kw)
+        with _spans.span("repro.dispatch.call"):
+            result = _run_attempt(plans, problem, backend, arrays, kw)
         if validate is not None and eager:
-            err = validate(problem, backend, result)
+            with _spans.span("repro.dispatch.validate"):
+                err = validate(problem, backend, result)
             if err is not None:
                 reason, health = err
                 raise SolveFailure(
@@ -430,32 +448,41 @@ def dispatch(
         # Demotions are deliberately NOT consulted here: they only steer
         # *screened* dispatches (validator or fault plan present), so an
         # earlier hostile operand can never re-route plain default traffic.
-        backend = select(problem, cache=cache, allow=allow)
+        with _spans.span("repro.dispatch.select"):
+            backend = select(problem, cache=cache, allow=allow)
+        sp.set(backend=backend.name, attempts=1)
         for hook in _DISPATCH_HOOKS:
             hook(problem, backend)
-        return backend.call(problem, *arrays, **kw)
+        with _spans.span("repro.dispatch.call"):
+            return backend.call(problem, *arrays, **kw)
 
     # --- escalation funnel -------------------------------------------------
     operand_faults = (ArithmeticError, np.linalg.LinAlgError, SolveFailure,
                       _faults.InjectedFault)
-    winner = select(problem, cache=cache, allow=allow)
-    rest = sorted(
-        (b for b in candidates(problem, allow=allow) if b.name != winner.name),
-        key=lambda b: b.priority(problem), reverse=True,
-    )
-    ordered = [winner] + rest
-    key = _shape_key(problem)
-    _tick_demotions(key)
-    live = [b for b in ordered if (key, b.name) not in _DEMOTIONS] or ordered
+    with _spans.span("repro.dispatch.select"):
+        winner = select(problem, cache=cache, allow=allow)
+        rest = sorted(
+            (b for b in candidates(problem, allow=allow) if b.name != winner.name),
+            key=lambda b: b.priority(problem), reverse=True,
+        )
+        ordered = [winner] + rest
+        key = _shape_key(problem)
+        _tick_demotions(key)
+        live = [b for b in ordered if (key, b.name) not in _DEMOTIONS] or ordered
     chain: list[dict] = []
     last_health = None
     for i, backend in enumerate(live):
+        sp.set(backend=backend.name, attempts=i + 1)
         for hook in _DISPATCH_HOOKS:
             hook(problem, backend)
         health = None
         try:
-            result = _run_attempt(plans, problem, backend, arrays, kw)
-            err = validate(problem, backend, result) if (validate and eager) else None
+            with _spans.span("repro.dispatch.call"):
+                result = _run_attempt(plans, problem, backend, arrays, kw)
+            err = None
+            if validate and eager:
+                with _spans.span("repro.dispatch.validate"):
+                    err = validate(problem, backend, result)
             if err is None:
                 return result
             reason, health = err
