@@ -7,7 +7,11 @@ scans).  The service exploits it twice:
 
 * **factorization cache** — an LRU keyed by matrix *fingerprint*
   (content hash of bytes + shape + dtype + bandwidth).  A hit skips the
-  factorization dispatch entirely and jumps straight to substitution;
+  factorization dispatch entirely and jumps straight to substitution.
+  A live ``jax.Array`` is immutable, so its fingerprint is computed once
+  and remembered for as long as the array lives: resubmitting the same
+  operator object costs no host copy and no hash.  Any other operand
+  (numpy, lists) can change in place and is hashed on every submit;
 * **RHS coalescing** — pending requests against one fingerprint hstack
   their RHS columns into a single wide solve dispatch
   (:func:`repro.core.solve.stack_rhs`).  Substitution columns are
@@ -67,7 +71,9 @@ mid-flush requeues every unprocessed entry with seq/deadline intact.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
+import weakref
 from collections import OrderedDict
 
 import jax
@@ -117,18 +123,53 @@ class DeadlineMiss:
     now: float
 
 
+# (id(array), bw) -> (weakref to the array, hex digest).  Shared by every
+# caller: the digest belongs to the immutable array, not to a service.
+_memo: dict[tuple[int, int], tuple[weakref.ref, str]] = {}
+
+
+def _forget(key, ref) -> None:
+    """Weakref callback: drop the memo entry of an array that died, unless
+    the key already holds a newer array's entry."""
+    if _memo.get(key, (None,))[0] is ref:
+        del _memo[key]
+
+
 def fingerprint(a, *, bw: int = 0) -> str:
     """Content hash identifying a matrix operand (dense or row-aligned
-    band): sha1 over the raw bytes + shape + dtype + bandwidth."""
+    band): sha1 over the raw bytes + shape + dtype + bandwidth.
+
+    The digest of a live ``jax.Array`` is memoized on the object (held
+    weakly) and ``bw``: a ``jax.Array`` cannot change, so the same object
+    always has the same digest.  Any other operand is hashed every time,
+    since it may have been written in place; so is an equal-content copy,
+    which gets the same digest.  A deleted (donated) array is never looked
+    up and raises in its host copy."""
+    return _fingerprint(a, int(bw))[0]
+
+
+def _fingerprint(a, bw: int, remember: bool = True) -> tuple[str, bool]:
+    """``(digest, memo hit)`` of :func:`fingerprint`; ``remember=False``
+    neither looks up nor stores ``a``."""
+    key = None
+    if remember and isinstance(a, jax.Array) and not a.is_deleted():
+        key = (id(a), bw)
     with spans.span("repro.service.fingerprint") as sp:
+        hit = _memo.get(key)
+        if hit is not None and hit[0]() is a:
+            sp.set(memo=True, bytes=a.nbytes)
+            return hit[1], True
         with spans.span("repro.service.fingerprint.to_host"):
             arr = np.asarray(a)
-        sp.set(bytes=arr.nbytes)
+        sp.set(memo=False, bytes=arr.nbytes)
         with spans.span("repro.service.fingerprint.hash"):
             h = hashlib.sha1()
-            h.update(str((arr.shape, arr.dtype.str, int(bw))).encode())
+            h.update(str((arr.shape, arr.dtype.str, bw)).encode())
             h.update(arr.tobytes())
-            return h.hexdigest()
+            digest = h.hexdigest()
+        if key is not None:
+            _memo[key] = (weakref.ref(a, functools.partial(_forget, key)), digest)
+        return digest, False
 
 
 @dataclasses.dataclass
@@ -159,6 +200,7 @@ class SolveServiceStats:
     escalations: int = 0  # registry escalation events observed during flushes
     quarantined: int = 0  # tickets short-circuited by the negative cache
     shed_deadline: int = 0  # tickets shed as DeadlineMiss at drain
+    fingerprint_memo_hits: int = 0  # submits whose operand digest was memoized
     last_refine_iterations: int | None = None  # refinement sweeps of the last
                                                # approximate solve (None = none ran)
 
@@ -257,6 +299,9 @@ class SolveService:
                     f"rank= produces factors guaranteed to {RAND_LU_RESIDUAL_BOUND:g} "
                     f"relative residual; request tolerance {tolerance:g} is tighter"
                 )
+        # only the caller's own jax.Array can come back: any other operand
+        # converts to a new array on every submit
+        remember = isinstance(a, jax.Array)
         a = jnp.asarray(a)
         b = jnp.asarray(b)
         n = int(a.shape[-2]) if bw else int(a.shape[-1])
@@ -265,8 +310,10 @@ class SolveService:
         ticket = self._tickets
         self._tickets += 1
         with spans.span("repro.service.submit", request=ticket, n=n, bw=bw, cols=cols):
+            fp, memo = _fingerprint(a, int(bw), remember)
+            self.stats.fingerprint_memo_hits += memo
             req = SolveRequest(
-                ticket=ticket, fp=fingerprint(a, bw=bw), a=a, b=b, bw=bw,
+                ticket=ticket, fp=fp, a=a, b=b, bw=bw,
                 deadline=deadline, tolerance=float(tolerance), rank=rank,
             )
             self._sched.submit(

@@ -1,5 +1,11 @@
 """Solve service tests: factorization cache hit/miss/evict, coalesced
-multi-RHS parity, factor-once/solve-many dispatch accounting."""
+multi-RHS parity, factor-once/solve-many dispatch accounting, and the
+fingerprint memo of live ``jax.Array`` operands."""
+import gc
+import hashlib
+import types
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +14,7 @@ import pytest
 from repro.core import make_diagonally_dominant
 from repro.core.banded import make_banded_dd
 from repro.kernels import ops as kops
+from repro.serve import solve_service
 from repro.serve.solve_service import SolveService, fingerprint
 
 
@@ -127,6 +134,107 @@ def test_fingerprint_sensitivity():
     assert fingerprint(a) != fingerprint(b)
     assert fingerprint(a) != fingerprint(a.astype(np.float64))
     assert fingerprint(a, bw=0) != fingerprint(a, bw=2)
+
+
+def _sha1(a, bw: int = 0) -> str:
+    """The fingerprint's digest, computed here from its definition."""
+    arr = np.asarray(a)
+    h = hashlib.sha1(str((arr.shape, arr.dtype.str, bw)).encode())
+    h.update(arr.tobytes())
+    return h.hexdigest()
+
+
+@pytest.fixture()
+def sha1_calls(monkeypatch):
+    """Counts the service's sha1 hashers (one per hashed operand)."""
+    calls = []
+
+    def sha1(*args):
+        calls.append(args)
+        return hashlib.sha1(*args)
+
+    monkeypatch.setattr(solve_service, "hashlib", types.SimpleNamespace(sha1=sha1))
+    return calls
+
+
+def _same(a):
+    return a
+
+
+def _copy(a):
+    return jnp.array(a, copy=True)
+
+
+def _mutated(a):
+    a[0, 1] += 0.5  # in place: still diagonally dominant
+    return a
+
+
+@pytest.mark.parametrize(
+    "as_operand, second, same_digest, hashes, memo_hits",
+    [(jnp.asarray, _same, True, 1, 1),
+     (jnp.asarray, _copy, True, 2, 0),
+     (np.array, _mutated, False, 2, 0)],
+    ids=["same_array", "equal_content_copy", "numpy_mutated_in_place"],
+)
+def test_fingerprint_memo_on_resubmit(dense_system, sha1_calls, as_operand, second,
+                                      same_digest, hashes, memo_hits):
+    """Only a resubmitted live jax.Array skips the host copy and sha1; an
+    equal-content copy is hashed to the same digest (and hits the factor
+    cache), a numpy operand written in place is hashed to a new one."""
+    a0, bs = dense_system
+    a = as_operand(np.asarray(a0))
+    svc = SolveService()
+    t1 = svc.submit(a, bs[0])
+    digest1 = _sha1(a)
+    a2 = second(a)
+    t2 = svc.submit(a2, bs[1])
+    assert len(sha1_calls) == hashes
+    assert svc.stats.fingerprint_memo_hits == memo_hits
+    out = svc.flush()
+    assert set(out) == {t1, t2}
+    assert list(svc._lru) == ([digest1] if same_digest else [digest1, _sha1(a2)])
+    assert (svc.stats.cache_hits, svc.stats.cache_misses) == ((1, 1) if same_digest else (0, 2))
+
+
+def test_fingerprint_memo_keys_on_bw(sha1_calls):
+    """``bw`` is part of the digest and of the memo's key."""
+    a = make_banded_dd(jax.random.PRNGKey(4), 32, 2)
+    d0, d2 = fingerprint(a), fingerprint(a, bw=2)
+    assert (d0, d2) == (_sha1(a, 0), _sha1(a, 2)) and d0 != d2
+    assert (fingerprint(a), fingerprint(a, bw=2)) == (d0, d2)
+    assert len(sha1_calls) == 2  # the repeats were memo hits
+
+
+def test_fingerprint_memo_holds_its_array_weakly():
+    a = make_diagonally_dominant(jax.random.PRNGKey(5), 16)
+    key, digest = (id(a), 0), fingerprint(a)
+    assert solve_service._memo[key][1] == digest == _sha1(a)
+    alive = weakref.ref(a)
+    del a
+    gc.collect()
+    assert alive() is None  # the memo kept no strong reference
+    assert key not in solve_service._memo
+
+
+@pytest.mark.parametrize("fingerprinted", [False, True], ids=["cold", "memoized"])
+@pytest.mark.parametrize("how", ["delete", "donate"])
+def test_deleted_operand_raises_at_submit(dense_system, how, fingerprinted):
+    """A deleted or donated array fails at submit even when its digest is
+    memoized: the memo is never consulted for it."""
+    a0, bs = dense_system
+    a = jnp.array(a0, copy=True)
+    if fingerprinted:
+        fingerprint(a)
+    if how == "delete":
+        a.delete()
+    else:
+        jax.jit(lambda x: x + 1.0, donate_argnums=0)(a)
+    assert a.is_deleted()
+    svc = SolveService()
+    with pytest.raises(RuntimeError, match="deleted"):
+        svc.submit(a, bs[0])
+    assert svc.stats.fingerprint_memo_hits == 0
 
 
 def test_deadline_orders_flush_groups(dense_system):

@@ -81,11 +81,10 @@ def test_service_records_its_span_tree(traced):
     fps = [s for s in found if s.name == "repro.service.fingerprint"]
     assert [parent(s) for s in fps] == ["repro.service.submit"] * 2
     assert [s.request for s in fps] == traced["tickets"]
-    assert all(s.attrs["bytes"] == N * N * 4 for s in fps)
-    for child in ("repro.service.fingerprint.to_host", "repro.service.fingerprint.hash"):
-        kids = [s for s in found if s.name == child]
-        assert [parent(s) for s in kids] == ["repro.service.fingerprint"] * 2
-        assert [s.request for s in kids] == traced["tickets"]
+    # svc.solve fingerprinted the operator before the trace: both digests
+    # come from the memo, with no host copy and no hash
+    assert all(s.attrs == {"memo": True, "bytes": N * N * 4} for s in fps)
+    assert not {"repro.service.fingerprint.to_host", "repro.service.fingerprint.hash"} & set(names)
 
     (flush,) = [s for s in found if s.name == "repro.service.flush"]
     assert flush.parent is None
@@ -114,6 +113,23 @@ def test_service_records_its_span_tree(traced):
     (lu_dispatch,) = [d for d in dispatches if d.parent == lu.id]
     assert lu_dispatch.attrs["traced"] is True and lu_dispatch.attrs["op"] == "factor"
     assert names.index("repro.service.flush") < names.index("repro.ops.lu")
+
+
+def test_first_fingerprint_of_a_fresh_array_records_its_hash():
+    a, b = _dominant(2)
+    svc = SolveService()
+    t0 = time.perf_counter()
+    with spans.recording():
+        tickets = [svc.submit(a, b), svc.submit(a, 2.0 * b)]
+    found = spans.recorded(t0, time.perf_counter())
+    fps = [s for s in found if s.name == "repro.service.fingerprint"]
+    assert [s.request for s in fps] == tickets
+    assert [s.attrs for s in fps] == [{"memo": False, "bytes": N * N * 4},
+                                      {"memo": True, "bytes": N * N * 4}]
+    for child in ("repro.service.fingerprint.to_host", "repro.service.fingerprint.hash"):
+        assert [s.parent for s in found if s.name == child] == [fps[0].id]
+    assert svc.stats.fingerprint_memo_hits == 1
+    svc.flush()
 
 
 def test_span_names_are_on_the_trace_host_plane(traced):
