@@ -6,7 +6,8 @@ Layout, all under ``bench/``, each part found by the name that
 
 - ``configs/<config>.json``: sizes, precision, limits, generator, reference;
 - ``traffic/<mix>.json``: the mix's parameters, read by ``loops/<loop>.py``;
-- ``generators/<generator>.py``: operators and right-hand sides from a key;
+- ``generators/<generator>.py``: operators and right-hand sides from a key,
+  and ``STRUCTURE`` (``"dense"``, or ``"banded"`` with ``bw(config)``);
 - ``metrics/<metric>.py``: ``value(run)`` of one metric, or ``None``;
 - ``work/<op>.py``: ``count(n, bw, k, itemsize)`` → least FLOPs and bytes;
 - ``peaks.json``: published peaks keyed by ``device_kind``.
@@ -86,6 +87,11 @@ def peaks(root: str, device_kind: str) -> dict:
     return table[device_kind]
 
 
+def bandwidth(generator, config: dict) -> int:
+    """Half-bandwidth of the generator's operators, 0 for dense ones."""
+    return generator.bw(config) if generator.STRUCTURE == "banded" else 0
+
+
 def key_of(seed: int):
     """A PRNG key from any whole number (seeds may pass 32 bits)."""
     import jax.numpy as jnp
@@ -151,6 +157,7 @@ class Run:
         self.setup_s = float("nan")
         self.reduced: dict | None = None
         self.device_kind = ""
+        self.devices: list = []  # the cell's chips, set by ``run.run_cell``
         self.notes: list[str] = []
         self._active = None
 
@@ -177,7 +184,7 @@ class Run:
             self.calls.append({
                 "span": self._active, "op": problem.op, "structure": problem.structure,
                 "n": problem.n, "bw": problem.bw, "k": problem.rhs, "dtype": problem.dtype,
-                "backend": backend.name,
+                "devices": problem.devices, "backend": backend.name,
             })
 
     def start_window(self) -> float:
@@ -224,7 +231,9 @@ class Run:
 def roofline(run: Run, span: str, op: str, structure: str, name: str):
     """Least time of the ``op`` calls made inside ``span`` spans over the
     device busy time inside those spans, in %.  ``None`` when there is
-    nothing to read."""
+    nothing to read.  A call counts the work of its whole problem, whatever
+    implements it, spread over the chips it spans; busy time is the mean
+    over the cell's chips."""
     calls = [c for c in run.calls if c["span"] == span and c["op"] == op and c["structure"] == structure]
     if not calls or run.reduced is None:
         return None
@@ -235,9 +244,9 @@ def roofline(run: Run, span: str, op: str, structure: str, name: str):
     t_flops = t_bytes = t_min = 0.0
     for c in calls:
         flops, nbytes = run.work(c)
-        t_flops += flops / pk["flops_bf16"]
-        t_bytes += nbytes / pk["hbm_bytes_s"]
-        t_min += max(flops / pk["flops_bf16"], nbytes / pk["hbm_bytes_s"])
+        t_flops += flops / pk["flops_bf16"] / c["devices"]
+        t_bytes += nbytes / pk["hbm_bytes_s"] / c["devices"]
+        t_min += max(flops / pk["flops_bf16"], nbytes / pk["hbm_bytes_s"]) / c["devices"]
     bound = "compute" if t_flops >= t_bytes else "memory"
     run.note(f"{name}: {len(calls)} calls, bound={bound}, flops_time_s={t_flops!r} "
              f"bytes_time_s={t_bytes!r} device_busy_in_{span}_s={busy!r}")
@@ -246,13 +255,13 @@ def roofline(run: Run, span: str, op: str, structure: str, name: str):
 
 def mfu(run: Run, ops: tuple[str, ...], name: str):
     """Useful FLOPs of the window's ``ops`` calls over the window and the
-    bf16 peak, in %."""
+    bf16 peak of the cell's chips, in %."""
     calls = [c for c in run.calls if c["op"] in ops]
     flops = sum(run.work(c)[0] for c in calls)
     if not flops or run.window_s <= 0:
         return None
     run.note(f"{name}: {len(calls)} calls, useful_flops={flops!r} window_s={run.window_s!r}")
-    return 100.0 * flops / run.window_s / run.peaks()["flops_bf16"]
+    return 100.0 * flops / run.window_s / (len(run.devices) * run.peaks()["flops_bf16"])
 
 
 def idle_share(run: Run):

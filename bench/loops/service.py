@@ -14,6 +14,9 @@ window runs whole ticks until ``--seconds`` have passed.  A request's latency
 runs from its ``submit`` to its answer being ready; a request that returns no
 answer counts as failed.  Spans: ``submit``, ``flush``.
 
+A banded generator's operators are row-aligned bands of half-width
+``bw(config)``, submitted with ``bw=``.  The service runs on one chip.
+
 Traffic parameters: ``operators``, ``requests_per_tick``, ``zipf_s``,
 ``cache_entries``, ``pattern_seed``, ``check_sample``.
 """
@@ -26,7 +29,7 @@ import jax
 import numpy as np
 
 from bench import check
-from bench.core import OPERATOR, RHS, WARM, fold
+from bench.core import OPERATOR, RHS, WARM, bandwidth, fold
 
 
 def zipf_counts(ranks: int, requests: int, s: float) -> list[int]:
@@ -40,6 +43,9 @@ def zipf_counts(ranks: int, requests: int, s: float) -> list[int]:
 
 def setup(run) -> dict:
     gen, cfg, t = run.generator, run.config, run.traffic
+    if len(run.devices) > 1:
+        raise ValueError(f"{run.cell}: service traffic runs on one chip, the cell asks for {len(run.devices)}")
+    bw = bandwidth(gen, cfg)
     m, k = t["operators"], t["requests_per_tick"]
     operators = jax.jit(lambda key: tuple(gen.operator(fold(key, OPERATOR, j), cfg) for j in range(m)))
     rhs_tick = jax.jit(lambda key, tick: tuple(gen.rhs(fold(key, RHS, tick, j), cfg) for j in range(k)))
@@ -49,6 +55,8 @@ def setup(run) -> dict:
         "counts": zipf_counts(m, k, t["zipf_s"]),
         "rng": np.random.default_rng([int(run.seed) % 2**63, 11]),
         "svc": run.solver.service(t["cache_entries"]),
+        "bw": bw,
+        "submit_kw": {"bw": bw} if bw else {},
     }
     _tick(run, state, fold(run.key, WARM), 0, record=False)
     return state
@@ -61,12 +69,12 @@ def _tick(run, state, key, tick: int, record: bool = True):
     pattern = np.random.default_rng([run.traffic["pattern_seed"], tick])
     order = [int(holder[ranks[j]]) for j in pattern.permutation(len(ranks))]
     bs = jax.block_until_ready(state["rhs_tick"](key, tick))
-    svc, ops = state["svc"], state["ops"]
+    svc, ops, kw = state["svc"], state["ops"], state["submit_kw"]
     submitted, tickets = [], []
     for j, op in enumerate(order):
         submitted.append(time.perf_counter())
         with run.span("submit"):
-            tickets.append(svc.submit(ops[op], bs[j]))
+            tickets.append(svc.submit(ops[op], bs[j], **kw))
     with run.span("flush"):
         out = svc.flush()
         ready = []
@@ -110,6 +118,6 @@ def check_answers(run, state: dict) -> dict:
         a = np.asarray(state["ops"][op])
         b = np.stack([np.asarray(state["rhs_tick"](run.key, tick)[j]) for tick, j, _ in items], axis=1)
         x = np.stack([np.asarray(x) for _, _, x in items], axis=1)
-        rel.extend(check.residuals(a, b, x))
+        rel.extend(check.residuals(a, b, x, bw=state["bw"]))
         del a
     return {"relative_residual": max(rel, default=float("nan"))}

@@ -53,10 +53,17 @@ def prepare_environment():
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def _trace_dir(cell: str) -> str:
-    path = os.path.join(ARTIFACTS, "trace", cell)
+def _trace_dir(root: str, cell: str) -> str:
+    """The cell's trace directory under ``root``, emptied: runs of one cell
+    from different checkouts never share it."""
+    path = os.path.join(root, "artifacts", "bench", "trace", cell)
     shutil.rmtree(path, ignore_errors=True)
     return path
+
+
+def _plane(device) -> str:
+    """The name of ``device``'s plane in a profiler trace."""
+    return f"/device:{device.platform.upper()}:{device.id}"
 
 
 def _memory_peak(devices) -> int:
@@ -102,13 +109,14 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *, root: str = R
         solver = system.Program()
     run = core.Run(root, cell, config, traffic, gen, solver, seed, seconds, trace)
     run.device_kind = used[0].device_kind
+    run.devices = used
 
     compiles = core.Compiles()
     hook = solvers.add_dispatch_hook(run.on_dispatch)
     try:
         state = loop.setup(run)
         if trace:
-            log_dir = _trace_dir(cell)
+            log_dir = _trace_dir(root, cell)
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 1
@@ -118,7 +126,8 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *, root: str = R
         if trace:
             jax.profiler.stop_trace()
             run.reduced = trace_reduce.reduce(trace_reduce.latest_trace(log_dir), TRACED_SPANS,
-                                              host_ops_allowed=not require_chip)
+                                              host_ops_allowed=not require_chip,
+                                              planes=[_plane(d) for d in used])
             shutil.rmtree(log_dir, ignore_errors=True)
     finally:
         solvers.remove_dispatch_hook(hook)

@@ -1,6 +1,9 @@
 """A run with the timed path broken underneath reads ``correct`` false, for
 each fault a cell can have; the same run unbroken reads true.  The chip
-check is skipped; everything else is the harness's own run."""
+check is skipped; everything else is the harness's own run.  Each fault
+breaks the dense and the banded ops alike, so a cell added later is broken
+whatever its structure and chips."""
+import dataclasses
 import json
 import os
 
@@ -14,32 +17,33 @@ BM = core.load_benchmark()
 CELLS = [w["name"] for w in BM["workloads"]]
 
 
-def _coalesces(cell: str) -> bool:
-    """Whether the cell's traffic sends one operator two requests in a tick."""
-    t = core.data(core.ROOT, "traffic", core.workload(BM, cell)["traffic"])
-    return t["loop"] == "service" and t["requests_per_tick"] > t["operators"]
+def _unchanged(f, a):
+    """Factors ``f`` of ``a`` with their state left as the operand."""
+    from repro.core.spike import SpikeFactors, partition_band
+
+    if isinstance(f, SpikeFactors):
+        return dataclasses.replace(f, local_lu=partition_band(a, bw=f.bw, devices=f.devices)[0])
+    return f.with_meta(packed=a, linv=None, uinv=None, tlo=None, tup=None)
 
 
 def _unchanged_state(ops, mp):
     """The factorization returns its operand as the factors."""
-    orig = ops.lu
+    for name in ("lu", "banded_lu"):
+        def fake(a, *args, _orig=getattr(ops, name), **kw):
+            out = _orig(a, *args, **kw)
+            f, rec = out if isinstance(out, tuple) else (out, None)
+            f = _unchanged(f, a)
+            return f if rec is None else (f, rec)
 
-    def fake(a, *args, **kw):
-        out = orig(a, *args, **kw)
-        f, rec = out if isinstance(out, tuple) else (out, None)
-        f = f.with_meta(packed=a, linv=None, uinv=None, tlo=None, tup=None)
-        return f if rec is None else (f, rec)
-
-    mp.setattr(ops, "lu", fake)
+        mp.setattr(ops, name, fake)
 
 
 def _wrap_solves(ops, mp, change):
-    orig = ops.lu_solve
+    for name in ("lu_solve", "banded_solve"):
+        def fake(f, b, *args, _orig=getattr(ops, name), **kw):
+            return change(_orig, f, b, args, kw)
 
-    def fake(f, b, *args, **kw):
-        return change(orig, f, b, args, kw)
-
-    mp.setattr(ops, "lu_solve", fake)
+        mp.setattr(ops, name, fake)
 
 
 def _answer_altered(ops, mp):
@@ -63,10 +67,40 @@ def _half_batch(ops, mp):
     _wrap_solves(ops, mp, change)
 
 
+def _exchange_left_out(ops, mp):
+    """A factorization split over chips drops what the chips exchange: no
+    spikes, and the reduced system is the identity, so each chip solves its
+    own rows alone."""
+    from repro.core.spike import SpikeFactors
+
+    orig = ops.banded_lu
+
+    def fake(a, *args, **kw):
+        f = orig(a, *args, **kw)
+        assert isinstance(f, SpikeFactors), type(f)
+        return dataclasses.replace(f, w_spikes=jnp.zeros_like(f.w_spikes), v_spikes=jnp.zeros_like(f.v_spikes),
+                                   reduced=jnp.eye(f.reduced.shape[0], dtype=f.reduced.dtype))
+
+    mp.setattr(ops, "banded_lu", fake)
+
+
 FAULTS = {"unchanged_state": _unchanged_state, "answer_altered": _answer_altered,
-          "half_batch": _half_batch}
-CASES = [(cell, fault) for cell in CELLS for fault in FAULTS
-         if fault != "half_batch" or _coalesces(cell)]
+          "half_batch": _half_batch, "exchange_left_out": _exchange_left_out}
+
+
+def applies(bm: dict, root: str, cell: str, fault: str) -> bool:
+    """Whether ``cell`` can have ``fault``: a coalesced solve needs a mix
+    that sends one operator two requests a tick; an exchange needs chips."""
+    w = core.workload(bm, cell)
+    if fault == "half_batch":
+        t = core.data(root, "traffic", w["traffic"])
+        return t["loop"] == "service" and t["requests_per_tick"] > t["operators"]
+    if fault == "exchange_left_out":
+        return w["chips"] > 1
+    return True
+
+
+CASES = [(cell, fault) for cell in CELLS for fault in FAULTS if applies(BM, core.ROOT, cell, fault)]
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -53,7 +53,9 @@ def test_cell_resolves_to_its_files(cell):
     config = core.data(core.ROOT, "configs", w["config"])
     traffic = core.data(core.ROOT, "traffic", w["traffic"])
     gen = core.module(core.ROOT, "generators", config["generator"])
-    assert gen.STRUCTURE == "dense"
+    assert gen.STRUCTURE in ("dense", "banded")
+    if gen.STRUCTURE == "banded":
+        assert gen.bw(config) >= 1
     loop = core.module(core.ROOT, "loops", traffic["loop"])
     for fn in ("setup", "window", "release", "check_answers"):
         assert callable(getattr(loop, fn))
@@ -61,7 +63,19 @@ def test_cell_resolves_to_its_files(cell):
     assert callable(ref.factor) and callable(ref.solve)
     assert config["limits"] and all(v > 0 for v in config["limits"].values())
     for structure_op in ("lu", "solve"):
+        assert os.path.isfile(core.part_path(core.ROOT, "work", f"{gen.STRUCTURE}_{structure_op}", ".py"))
         core.module(core.ROOT, "work", f"{gen.STRUCTURE}_{structure_op}")
+
+
+def test_each_pair_of_config_and_traffic_is_one_cell():
+    pairs = [(w["config"], w["traffic"]) for w in BM["workloads"]]
+    assert len(pairs) == len(set(pairs)), pairs
+
+
+def test_four_chip_cells_within_quota():
+    """At most half of the cells, rounded down, take four chips, or one."""
+    four = [w["name"] for w in BM["workloads"] if w["chips"] == 4]
+    assert len(four) <= len(CELLS) // 2 or len(four) == 1, four
 
 
 @pytest.mark.parametrize("cell", CELLS)

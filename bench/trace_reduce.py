@@ -6,6 +6,8 @@
   ``hlo_op`` stat; on a chip a trace with no device plane is an error.
 - Busy time is the union of the operations' intervals inside the ``window``
   span, averaged over the devices; the idle share is ``1 - busy / window``.
+  Only the cell's own device planes, which the caller names, count; one of
+  them that ran nothing counts as busy 0.
 - Device time inside a kind of host span is the busy time that falls inside
   the spans of that name.
 - The breakdown lists the device operations that took the most time, and the
@@ -38,23 +40,22 @@ def _stats(event) -> dict:
         return {}
 
 
-def read(path: str, host_ops_allowed: bool = False):
-    """``(ops_by_device, host_spans)``: per device a list of
-    ``(name, start_ns, end_ns)``, and every host event as the same triple."""
+def read(path: str, planes, host_ops_allowed: bool = False):
+    """``(ops_by_device, host_spans)``: for each of ``planes``, the names of
+    the cell's device planes, a list of ``(name, start_ns, end_ns)``, empty
+    where it ran nothing; and every host event as the same triple."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    devices: dict[str, list] = {}
+    found: dict[str, list] = {}
     host, host_ops = [], []
     for plane in data.planes:
         if plane.name.startswith("/device:"):
-            ops = [
+            found[plane.name] = [
                 (e.name, e.start_ns, e.start_ns + e.duration_ns)
                 for line in plane.lines if line.name == "XLA Ops"
                 for e in line.events
             ]
-            if ops:
-                devices[plane.name] = ops
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
@@ -62,9 +63,9 @@ def read(path: str, host_ops_allowed: bool = False):
                     host.append(span)
                     if "hlo_op" in _stats(e):
                         host_ops.append(span)
-    if not devices and host_ops_allowed and host_ops:
-        devices["/host:CPU"] = host_ops
-    return devices, host
+    if not any(found.values()) and host_ops_allowed and host_ops:
+        return {"/host:CPU": host_ops}, host
+    return {name: found.get(name, []) for name in planes}, host
 
 
 def union(intervals) -> list[tuple[float, float]]:
@@ -82,14 +83,14 @@ def covered(merged, lo: float, hi: float) -> float:
     return sum(max(0.0, min(e, hi) - max(s, lo)) for s, e in merged)
 
 
-def reduce(path: str, span_names, host_ops_allowed: bool = False) -> dict:
-    devices, host = read(path, host_ops_allowed)
+def reduce(path: str, span_names, *, planes, host_ops_allowed: bool = False) -> dict:
+    devices, host = read(path, planes, host_ops_allowed)
     windows = [(s, e) for name, s, e in host if name == WINDOW]
     if not windows:
         raise ValueError(f"no {WINDOW!r} span in {path}")
     w0, w1 = windows[0]
     spans = sorted((s, e, name) for name, s, e in host if name in span_names and s < w1 and e > w0)
-    if not devices:
+    if not any(devices.values()):
         raise ValueError(f"no device operation in {path}")
 
     busy, busy_in = [], defaultdict(list)
